@@ -48,6 +48,8 @@ def main() -> None:
     only = set(args.only.split(",")) if args.only else None
     if args.smoke and only is None:
         only = set(SMOKE_MODULES)
+    from repro.compile_cache import setup_compile_cache
+    setup_compile_cache()
 
     print("name,us_per_call,derived")
     failed = []

@@ -451,15 +451,29 @@ _TP_SCRIPT = textwrap.dedent("""
 """)
 
 
+def _require_cpu() -> None:
+    """The serve suite's TP phase starts a JAX child process. On a chip
+    host this process already holds the chip, so the child could not get
+    it: refuse before any work instead of hanging."""
+    backend = jax.default_backend()
+    if backend != "cpu":
+        raise RuntimeError(
+            f"benchmarks.serve_bench runs its tensor-parallel phase in a "
+            f"forced-host-device child process, which only works when this "
+            f"process runs on the CPU; it runs on {backend!r}, whose chip "
+            f"this process holds. Run it with JAX_PLATFORMS=cpu.")
+
+
 def add_tp_records(suite: BenchSuite, *, smoke: bool) -> None:
     """``serve/tp*`` records: 2-forced-host-device run of the mesh engine
     (shard_map and GSPMD paths) against the single-device baseline, token
     parity asserted inside the subprocess. Forced host devices measure
     PLUMBING overhead on CPU (a 1-core container shows TP as pure cost) —
     the record's job is tracking that overhead and the per-device cache
-    split, not projecting TPU scaling."""
+    split, not projecting TPU scaling. CPU only: see :func:`_require_cpu`."""
+    _require_cpu()
     env = dict(os.environ)
-    env.setdefault("JAX_PLATFORMS", jax.default_backend())
+    env["JAX_PLATFORMS"] = "cpu"
     env["XLA_FLAGS"] = ("--xla_force_host_platform_device_count=2 "
                         + env.get("XLA_FLAGS", "")).strip()
     env["PYTHONPATH"] = (str(repo_root() / "src") + os.pathsep
@@ -488,6 +502,7 @@ def add_tp_records(suite: BenchSuite, *, smoke: bool) -> None:
 
 
 def main(smoke: bool = False) -> None:
+    _require_cpu()
     suite = BenchSuite("serve", smoke=smoke)
     # machine-speed stamp: the SLO gate on FUTURE runs divides out this
     # machine's speed relative to whoever committed the trajectory

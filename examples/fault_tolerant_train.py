@@ -22,6 +22,7 @@ from repro.checkpoint import ckpt
 from repro.configs.base import get_config, reduced
 from repro.data.pipeline import SyntheticCorpus
 from repro.ft.monitor import HeartbeatMonitor, plan_remesh
+from repro.launch.mesh import make_mesh
 from repro.launch.train import build_trainer
 from repro.train import loop as tl
 
@@ -31,7 +32,7 @@ corpus = SyntheticCorpus(cfg.vocab_size, seed=11)
 FAIL_AT = 6
 
 print("== phase 1: (data=4, model=2) mesh ==")
-mesh = jax.make_mesh((4, 2), ("data", "model"))
+mesh = make_mesh((4, 2), ("data", "model"))
 jitted, shardings, _ = build_trainer(cfg, mesh, total_steps=20)
 monitor = HeartbeatMonitor(num_hosts=4, timeout_s=5.0)
 with mesh:
@@ -58,7 +59,7 @@ plan = plan_remesh(alive, model=2)
 print(f"\n== elastic re-mesh: {alive} devices alive -> "
       f"(data={plan.data}, model={plan.model}); resume from step {last} ==")
 
-mesh2 = jax.make_mesh((plan.data, plan.model), ("data", "model"))
+mesh2 = make_mesh((plan.data, plan.model), ("data", "model"))
 jitted2, shardings2, _ = build_trainer(cfg, mesh2, total_steps=20)
 with mesh2:
     template = tl.init_train_state(jax.random.PRNGKey(0), cfg)

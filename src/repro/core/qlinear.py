@@ -36,7 +36,8 @@ import jax.numpy as jnp
 from repro.core import formats as fmt_mod
 from repro.core.quantize import QTensor
 
-__all__ = ["qmatmul", "resolve_mode", "QLINEAR_MODES", "QMATMUL_BACKENDS"]
+__all__ = ["qmatmul", "resolve_mode", "resolve_backend", "QLINEAR_MODES",
+           "QMATMUL_BACKENDS"]
 
 QLINEAR_MODES = ("dequant", "weights", "activations", "auto")
 QMATMUL_BACKENDS = ("auto", "ref", "pallas")
@@ -50,6 +51,18 @@ def resolve_mode(x: jax.Array, m, mode: str) -> str:
     for d in x.shape[:-1]:
         rows *= d
     return "activations" if rows <= m.n else "weights"
+
+
+def resolve_backend(backend: str, fmt: str, mode: str) -> str:
+    """The implementation (``"pallas"`` or ``"ref"``) a ``fmt`` weight runs
+    under ``backend``/``mode`` on this process's default device."""
+    if backend not in QMATMUL_BACKENDS:
+        raise ValueError(f"backend {backend!r} not in {QMATMUL_BACKENDS}")
+    if not fmt_mod.get_format(fmt).supports_fused or mode == "dequant":
+        return "ref"
+    if backend == "auto":
+        return "pallas" if jax.default_backend() == "tpu" else "ref"
+    return backend
 
 
 def qmatmul(
@@ -88,17 +101,12 @@ def qmatmul(
         raise ValueError(f"qmatmul expects 2-D weights, got shape {m.shape}")
     if mode not in QLINEAR_MODES:
         raise ValueError(f"mode {mode!r} not in {QLINEAR_MODES}")
-    if backend not in QMATMUL_BACKENDS:
-        raise ValueError(f"backend {backend!r} not in {QMATMUL_BACKENDS}")
 
     spec = fmt_mod.get_format(m.fmt)
     mode = resolve_mode(x, m, mode)
-    if not spec.supports_fused or mode == "dequant":
-        backend = "ref"
-        if not spec.supports_fused:
-            mode = "dequant"  # non-ternary formats only store dense values
-    elif backend == "auto":
-        backend = "pallas" if jax.default_backend() == "tpu" else "ref"
+    backend = resolve_backend(backend, m.fmt, mode)
+    if not spec.supports_fused:
+        mode = "dequant"  # non-ternary formats only store dense values
 
     act = (act_quant and spec.supports_fused and m.act_quant
            and mode != "dequant")

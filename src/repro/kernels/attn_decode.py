@@ -46,10 +46,12 @@ serving is ragged), so pad tiles and unwritten cache slots contribute
 nothing.
 
 Dispatch mirrors qmatmul: ``backend="auto"`` runs the kernel on real TPU
-hardware for power-of-two head dims with HD a lane multiple, and falls back
-to the jnp reference — the same math as einsums — in interpret mode or for
-odd shapes; ``backend="pallas"`` on an unsupported shape fails fast with a
-ValueError naming the gate instead of dying in Pallas lowering. The
+hardware for power-of-two head dims (64 included: a (TT, 64) code block
+spans the array's whole minor dim, which the TPU block rule accepts), and
+falls back to the jnp reference — the same math as einsums — in interpret
+mode or for non-pow2 shapes; ``backend="pallas"`` on an unsupported shape
+fails fast with a ValueError naming the gate instead of dying in Pallas
+lowering. The
 backends share score/weight formulas exactly (scores from codes, V scale
 folded into the weight row), so greedy token streams are identical.
 
@@ -81,7 +83,8 @@ __all__ = [
     "attn_q8_pallas", "attn_decode_q8_pallas", "decode_attn_q8",
     "decode_attn_q8_ref", "prefill_attn_q8", "prefill_attn_q8_ref",
     "paged_row_table", "paged_to_dense",
-    "kernel_supported", "DEFAULT_TT", "DEFAULT_TQ", "ATTN_BACKENDS",
+    "kernel_supported", "resolve_attn_path", "DEFAULT_TT", "DEFAULT_TQ",
+    "ATTN_BACKENDS",
 ]
 
 DEFAULT_TT = 256  # key-tile width (tokens streamed per grid step)
@@ -90,34 +93,40 @@ NEG_INF = -1e30
 ATTN_BACKENDS = ("auto", "ref", "pallas")
 
 
-def kernel_supported(head_dim: int, *, interpret: bool) -> bool:
-    """Shape gate for the fused kernel. Interpret mode takes any pow2
-    head_dim (tests sweep the zoo's 32..128); real TPU lowering additionally
-    wants HD to fill whole 128-wide lanes."""
-    if not is_pow2(head_dim):
-        return False
-    return interpret or head_dim % 128 == 0
+def kernel_supported(head_dim: int) -> bool:
+    """Shape gate for the fused kernel: a power-of-two head_dim (the FWHT
+    the cache codec rotates by needs one). Every such width from 32 to 256
+    compiles for TPU v5e, dense and paged, decode and prefill
+    (tests/test_tpu_compile.py pins 64 and 128)."""
+    return is_pow2(head_dim)
 
 
 def _use_kernel(backend: str, head_dim: int, *, interpret: bool) -> bool:
     """Resolve the backend knob to kernel-or-ref, failing FAST (mirroring
     qmatmul's dispatch errors) when ``backend="pallas"`` is forced onto a
-    shape the kernel can't lower — a non-pow2 or, on real TPU, a
-    lane-partial head_dim would otherwise die deep inside Pallas."""
+    shape the kernel can't lower — a non-pow2 head_dim would otherwise die
+    deep inside Pallas. ``"auto"`` takes the kernel wherever it compiles
+    for the chip (never in interpret mode: the reference is the CPU path)."""
     if backend not in ATTN_BACKENDS:
         raise ValueError(f"backend {backend!r} not in {ATTN_BACKENDS}")
     if backend == "pallas":
-        if not kernel_supported(head_dim, interpret=interpret):
-            gate = ("must be a power of two" if not is_pow2(head_dim)
-                    else "must fill whole 128-wide lanes on real TPU "
-                         "(head_dim % 128 == 0)")
+        if not kernel_supported(head_dim):
             raise ValueError(
-                f"attention kernel shape gate: head_dim {head_dim} {gate}; "
-                f"use backend='ref' or 'auto' for this shape")
+                f"attention kernel shape gate: head_dim {head_dim} must be a "
+                f"power of two; use backend='ref' or 'auto' for this shape")
         return True
     if backend == "ref":
         return False
-    return not interpret and kernel_supported(head_dim, interpret=interpret)
+    return not interpret and kernel_supported(head_dim)
+
+
+def resolve_attn_path(backend: str, head_dim: int) -> str:
+    """``"pallas"`` or ``"ref"``: the implementation the quantized-cache
+    attention runs for ``backend`` on this process's default device."""
+    from repro.kernels.ops import auto_interpret  # local: avoid import cycle
+
+    use = _use_kernel(backend, head_dim, interpret=auto_interpret())
+    return "pallas" if use else "ref"
 
 
 def _tile_limit(len_val, off_val, qi, *, tq: int, causal: bool):
@@ -142,9 +151,9 @@ def _attn_q8_kernel(
     off_ref,  # (R,) int32 scalar-prefetch — absolute position of query 0
     q_ref,    # (1, TQ, G, HD) f32 — rotated query tile
     kc_ref,   # (1, TT, HD) int8 — K codes tile
-    ks_ref,   # (1, TT) f32 — K per-token scales
+    ks_ref,   # (1, 1, TT) f32 — K per-token scales
     vc_ref,   # (1, TT, HD) int8 — V codes tile
-    vs_ref,   # (1, TT) f32 — V per-token scales
+    vs_ref,   # (1, 1, TT) f32 — V per-token scales
     o_ref,    # (1, TQ, G, HD) f32 — unnormalized weighted V sum
     m_ref,    # (1, TQ, G, 1) f32 — running max
     l_ref,    # (1, TQ, G, 1) f32 — running denominator
@@ -189,7 +198,7 @@ def _attn_q8_kernel(
         # dequantize-free scores: (Hq).(Hk) == q.k, per-token scale on row
         s = jax.lax.dot_general(q, kc, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32)
-        s = s * (ks_ref[...] * sm_scale)  # (rows, TT) * (1, TT)
+        s = s * (ks_ref[0] * sm_scale)  # (rows, TT) * (1, TT)
 
         kpos = t * tt + jax.lax.broadcasted_iota(jnp.int32, (1, tt), 1)
         valid = kpos < len_ref[r]  # (1, TT)
@@ -211,7 +220,7 @@ def _attn_q8_kernel(
         mx_ref[...] = m_new
         dn_ref[...] = dn_ref[...] * alpha + jnp.sum(p, axis=-1, keepdims=True)
         # V dequant folded into the weight row: (p * v_scale) @ v_codes
-        pv = p * vs_ref[...]
+        pv = p * vs_ref[0]
         acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
             pv, vc_ref[0].astype(jnp.float32), (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
@@ -326,7 +335,8 @@ def attn_q8_pallas(
         return (tbl_ref[i, ti // tpb], ti % tpb, 0)
 
     def kv_scale_tile(i, qi, ti, *refs):
-        return (kv_tile_paged if paged else kv_tile)(i, qi, ti, *refs)[:2]
+        row, tile, _ = (kv_tile_paged if paged else kv_tile)(i, qi, ti, *refs)
+        return (row, 0, tile)
 
     kv_map = kv_tile_paged if paged else kv_tile
 
@@ -346,9 +356,9 @@ def attn_q8_pallas(
         in_specs=[
             pl.BlockSpec((1, tq, g, hd), q_map),
             pl.BlockSpec((1, tt, hd), kv_map),
-            pl.BlockSpec((1, tt), kv_scale_tile),
+            pl.BlockSpec((1, 1, tt), kv_scale_tile),
             pl.BlockSpec((1, tt, hd), kv_map),
-            pl.BlockSpec((1, tt), kv_scale_tile),
+            pl.BlockSpec((1, 1, tt), kv_scale_tile),
         ],
         out_specs=[
             pl.BlockSpec((1, tq, g, hd), q_map),
@@ -374,10 +384,17 @@ def attn_q8_pallas(
         ],
         interpret=interpret,
     )(*scalars, q_rot.astype(jnp.float32), k_codes,
-      k_scale.astype(jnp.float32), v_codes, v_scale.astype(jnp.float32))
+      _scale_rows(k_scale), v_codes, _scale_rows(v_scale))
     if pad_q:
         out, m, l = out[:, :tq_total], m[:, :tq_total], l[:, :tq_total]
     return out, m, l
+
+
+def _scale_rows(scale: jax.Array) -> jax.Array:
+    """(R, T) per-token scales -> (R, 1, T) f32: a unit sublane axis lets
+    the kernel tile the time axis as (1, 1, TT), which meets the TPU's
+    (8, 128) block rule for any R (a (1, TT) block over (R, T) does not)."""
+    return scale.astype(jnp.float32)[:, None, :]
 
 
 def _drop_table_ref(kernel, len_ref, off_ref, tbl_ref, *rest):

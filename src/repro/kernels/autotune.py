@@ -45,7 +45,7 @@ __all__ = [
 DEFAULT_TM = 256
 DEFAULT_TN = 256
 _TM_LADDER = (8, 16, 32, 64, 128, 256)
-_TN_LADDER = (64, 128, 256, 512)
+_TN_LADDER = (128, 256, 512)  # partial strips must fill 128-wide lanes
 _TQ_LADDER = (32, 64, 128, 256)   # attention query-tile widths
 _TT_LADDER = (128, 256, 512)      # attention key-tile widths
 

@@ -7,16 +7,20 @@ what matters is draining the packed planes from HBM at full bandwidth.
 
 This kernel is the memory-side specialization for M <= ``MATVEC_MAX_M``:
 
-* **No M grid.** The grid is (NB, KB) — output strips N-major, reduction
-  innermost — so the packed planes of each strip stream contiguously and
-  exactly once; there is no M loop to re-stream them for.
+* **No M grid.** The grid is (NB, KB) — output strips outermost,
+  reduction innermost — so the packed planes of each strip stream exactly
+  once; there is no M loop to re-stream them for.
 * **No x-tile machinery.** x rides along as one thin (M, 256) block per
   reduction step; the whole activation row set stays VREG-resident.
+* **Lane-dense planes.** The packed planes arrive in the K-major
+  ``(KB, 64|32, N)`` layout of :func:`~repro.kernels.itq3_matmul.kernel_planes`,
+  one ``(64, TN)`` / ``(32, TN)`` block per grid step with the output
+  features on the lanes.
 * **(M, TN) register-tile accumulator.** One f32 scratch tile accumulates
   across KB and flushes once per strip.
 
 The weight-tile expansion is byte-for-byte the tiled kernel's
-``dequant_rotate_tile`` (same chunk order, same MXU slices, K ascending),
+``dequant_rotate_tile`` (same chunk order, same MXU pass, K ascending),
 so results are **bit-identical** to ``itq3_matmul_pallas`` for every format
 in the ternary family — ``qmatmul`` dispatches between them purely by shape
 (see kernels/ops.py).
@@ -32,8 +36,8 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.fwht import hadamard_matrix
 from repro.kernels.itq3_matmul import (
-    BLOCK, _accumulate_int8, decode_wint_tile, dequant_rotate_tile,
-    pad_packed_n,
+    BLOCK, _accumulate_int8, _float_tile, _int8_tile, _plane_specs,
+    kernel_planes, lane_tile,
 )
 
 __all__ = ["itq3_matvec_pallas", "itq3_matvec_int8_pallas", "MATVEC_MAX_M"]
@@ -44,10 +48,10 @@ MATVEC_MAX_M = 16  # decode / small-batch regime; above this, tile the M dim
 def _itq3_matvec_kernel(
     h_ref,    # (256, 256) f32 — Hadamard (only read when rotate_weights)
     x_ref,    # (M, 256) — reduction block k of the activations
-    p2_ref,   # (TN, 1, 64) uint8
-    p1_ref,   # (TN, 1, 32) uint8
-    sc_ref,   # (TN, 1) f32  |  (TN, 1, SUB) f32
-    zp_ref,   # (TN, 1) f32
+    p2_ref,   # (1, 64, TN) uint8
+    p1_ref,   # (1, 32, TN) uint8
+    sc_ref,   # (1, 1|SUB, TN) f32
+    zp_ref,   # (1, 1, TN) f32
     o_ref,    # (M, TN)
     acc_ref,  # scratch (M, TN) f32
     *,
@@ -62,12 +66,11 @@ def _itq3_matvec_kernel(
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    w = dequant_rotate_tile(h_ref, p2_ref[:, 0, :], p1_ref[:, 0, :],
-                            sc_ref, zp_ref, rotate_weights=rotate_weights,
-                            fivelevel=fivelevel, sub_blocks=sub_blocks)
+    w = _float_tile(h_ref, p2_ref, p1_ref, sc_ref, zp_ref,
+                    rotate_weights=rotate_weights, fivelevel=fivelevel,
+                    sub_blocks=sub_blocks)
     x = x_ref[...].astype(jnp.float32)
-    acc_ref[...] += jax.lax.dot_general(
-        x, w, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32)
+    acc_ref[...] += jnp.dot(x, w, preferred_element_type=jnp.float32)
 
     @pl.when(k == kb - 1)
     def _flush():
@@ -101,19 +104,10 @@ def itq3_matvec_pallas(
     if kpad != kb * BLOCK:
         raise ValueError(f"x K dim {kpad} != KB*256 = {kb * BLOCK}")
 
-    tn = max(1, min(tn, n))
-    plane2, plane1, scales, zps = pad_packed_n(
-        (-n) % tn, plane2, plane1, scales, zps)
-    np_ = plane2.shape[0]
-
-    scales = scales.astype(jnp.float32)
-    zps = zps.astype(jnp.float32)
+    tn = lane_tile(tn, n, interpret=interpret)
+    p2, p1, sc, zp = kernel_planes(tn, plane2, plane1, scales, zps)
+    np_ = p2.shape[-1]
     h = hadamard_matrix(BLOCK, dtype=jnp.float32)
-
-    if sub_blocks:
-        sc_spec = pl.BlockSpec((tn, 1, sub_blocks), lambda j, k: (j, k, 0))
-    else:
-        sc_spec = pl.BlockSpec((tn, 1), lambda j, k: (j, k))
 
     kernel = functools.partial(
         _itq3_matvec_kernel,
@@ -128,26 +122,23 @@ def itq3_matvec_pallas(
         in_specs=[
             pl.BlockSpec((BLOCK, BLOCK), lambda j, k: (0, 0)),  # H resident
             pl.BlockSpec((m, BLOCK), lambda j, k: (0, k)),
-            pl.BlockSpec((tn, 1, BLOCK // 4), lambda j, k: (j, k, 0)),
-            pl.BlockSpec((tn, 1, BLOCK // 8), lambda j, k: (j, k, 0)),
-            sc_spec,
-            pl.BlockSpec((tn, 1), lambda j, k: (j, k)),
+            *_plane_specs(tn, sc.shape[1], lambda j, k: (k, j)),
         ],
         out_specs=pl.BlockSpec((m, tn), lambda j, k: (0, j)),
         out_shape=jax.ShapeDtypeStruct((m, np_), out_dtype),
         scratch_shapes=[pltpu.VMEM((m, tn), jnp.float32)],
         interpret=interpret,
-    )(h, x, plane2, plane1, scales, zps)
+    )(h, x, p2, p1, sc, zp)
     return out[:, :n]
 
 
 def _itq3_matvec_int8_kernel(
     x_ref,    # (M, 256) int8 — reduction block k of the activation codes
     xs_ref,   # (M, 1) f32 — per-row activation scale
-    p2_ref,   # (TN, 1, 64) uint8
-    p1_ref,   # (TN, 1, 32) uint8
-    sc_ref,   # (TN, 1) f32  |  (TN, 1, SUB) f32
-    zp_ref,   # (TN, 1) f32 (integer-valued)
+    p2_ref,   # (1, 64, TN) uint8
+    p1_ref,   # (1, 32, TN) uint8
+    sc_ref,   # (1, 1|SUB, TN) f32
+    zp_ref,   # (1, 1, TN) f32 (integer-valued)
     o_ref,    # (M, TN)
     acc_ref,  # scratch (M, TN) f32
     *,
@@ -166,9 +157,9 @@ def _itq3_matvec_int8_kernel(
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    w = decode_wint_tile(p2_ref[:, 0, :], p1_ref[:, 0, :], zp_ref,
-                         fivelevel=fivelevel, sub_blocks=sub_blocks)
-    _accumulate_int8(acc_ref, x_ref[...], w, sc_ref, sub_blocks=sub_blocks)
+    w = _int8_tile(p2_ref, p1_ref, zp_ref, fivelevel=fivelevel,
+                   sub_blocks=sub_blocks)
+    _accumulate_int8(acc_ref, x_ref[...], w, sc_ref[0], sub_blocks=sub_blocks)
 
     @pl.when(k == kb - 1)
     def _flush():
@@ -207,19 +198,10 @@ def itq3_matvec_int8_pallas(
     if kpad != kb * BLOCK:
         raise ValueError(f"xq K dim {kpad} != KB*256 = {kb * BLOCK}")
 
-    tn = max(1, min(tn, n))
-    plane2, plane1, scales, zps = pad_packed_n(
-        (-n) % tn, plane2, plane1, scales, zps)
-    np_ = plane2.shape[0]
-
+    tn = lane_tile(tn, n, interpret=interpret)
+    p2, p1, sc, zp = kernel_planes(tn, plane2, plane1, scales, zps)
+    np_ = p2.shape[-1]
     xscale = xscale.astype(jnp.float32)
-    scales = scales.astype(jnp.float32)
-    zps = zps.astype(jnp.float32)
-
-    if sub_blocks:
-        sc_spec = pl.BlockSpec((tn, 1, sub_blocks), lambda j, k: (j, k, 0))
-    else:
-        sc_spec = pl.BlockSpec((tn, 1), lambda j, k: (j, k))
 
     kernel = functools.partial(
         _itq3_matvec_int8_kernel,
@@ -233,14 +215,11 @@ def itq3_matvec_int8_pallas(
         in_specs=[
             pl.BlockSpec((m, BLOCK), lambda j, k: (0, k)),
             pl.BlockSpec((m, 1), lambda j, k: (0, 0)),
-            pl.BlockSpec((tn, 1, BLOCK // 4), lambda j, k: (j, k, 0)),
-            pl.BlockSpec((tn, 1, BLOCK // 8), lambda j, k: (j, k, 0)),
-            sc_spec,
-            pl.BlockSpec((tn, 1), lambda j, k: (j, k)),
+            *_plane_specs(tn, sc.shape[1], lambda j, k: (k, j)),
         ],
         out_specs=pl.BlockSpec((m, tn), lambda j, k: (0, j)),
         out_shape=jax.ShapeDtypeStruct((m, np_), out_dtype),
         scratch_shapes=[pltpu.VMEM((m, tn), jnp.float32)],
         interpret=interpret,
-    )(xq, xscale, plane2, plane1, scales, zps)
+    )(xq, xscale, p2, p1, sc, zp)
     return out[:, :n]

@@ -14,7 +14,7 @@ vocabulary and dispatches twice:
 **shape** (which kernel runs the contraction):
 
   M <= MATVEC_MAX_M   -> kernels/itq3_matvec.py — the decode-shaped
-                         N-major streaming kernel (no M tiling); ``tm``
+                         weight-streaming kernel (no M tiling); ``tm``
                          is ignored there.
   M >  MATVEC_MAX_M   -> kernels/itq3_matmul.py — the tiled kernel, with
                          the weight-tile expansion hoisted across M tiles
@@ -27,7 +27,7 @@ kernel ran.
 ``tm``/``tn`` default to None = resolve via :mod:`repro.kernels.autotune`
 (cached per-device winners, deterministic defaults in interpret mode).
 ``interpret`` defaults to "auto": interpret=True unless running on real TPU
-hardware. All wrappers handle reduction-dim padding and arbitrary leading
+hardware (on a TPU it is never chosen implicitly). All wrappers handle reduction-dim padding and arbitrary leading
 batch dims.
 """
 from __future__ import annotations

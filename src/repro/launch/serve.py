@@ -54,6 +54,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.checkpoint import ckpt as ckpt_mod
+from repro.compile_cache import setup_compile_cache
 from repro.configs.base import get_config, mixed_precision_recipe, reduced as reduced_cfg
 from repro.models import lm
 from repro.models.layers import Runtime
@@ -179,6 +180,7 @@ def main() -> None:
                          "tokens per slot per step, one batched target "
                          "pass verifies all K+1 positions")
     args = ap.parse_args()
+    setup_compile_cache()
 
     cfg = get_config(args.arch)
     if args.reduced:

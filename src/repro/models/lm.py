@@ -174,13 +174,22 @@ def init_cache(cfg, batch: int, max_len: int, dtype=jnp.bfloat16,
 # Decoder stacks
 # ===========================================================================
 
+def _residual(x, rt):
+    """Keep the residual stream whole on every model-axis device. Column-
+    parallel projections return N-sharded outputs; gathering them here
+    (exact) lets the next norm reduce over D on one device, where a sharded
+    D would all-reduce per-shard partial sums in another float order than
+    one device does."""
+    return shard_hint(x, rt, "batch", "seq", None)
+
+
 def _dense_layer_apply(lp, x, rt, cfg, *, cache, pos, memory=None, causal=True,
                        token_cache=False):
     h, new_kv = attention_apply(
         lp["attn"], norm_apply(lp["ln1"], x, cfg.norm), rt, cfg,
         causal=causal, cache=None if cache is None else cache["attn"], pos=pos,
         token_cache=token_cache)
-    x = x + h
+    x = _residual(x + h, rt)
     aux = jnp.zeros((), jnp.float32)
     new_cache = None
     if "xattn" in lp:
@@ -188,7 +197,7 @@ def _dense_layer_apply(lp, x, rt, cfg, *, cache, pos, memory=None, causal=True,
             lp["xattn"], norm_apply(lp["ln_x"], x, cfg.norm), rt, cfg,
             cross=True, memory=memory,
             cache=None if cache is None else cache.get("xattn"))
-        x = x + xc
+        x = _residual(x + xc, rt)
         if cache is not None:
             new_cache = {"attn": new_kv, "xattn": new_xkv}
     elif cache is not None:
@@ -198,7 +207,7 @@ def _dense_layer_apply(lp, x, rt, cfg, *, cache, pos, memory=None, causal=True,
         m, aux = moe_mod.moe_apply(lp["moe"], hn, rt, cfg)
     else:
         m = mlp_apply(lp["mlp"], hn, rt, cfg.activation)
-    return x + m, new_cache, aux
+    return _residual(x + m, rt), new_cache, aux
 
 
 def _maybe_remat(body, rt):

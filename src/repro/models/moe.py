@@ -133,7 +133,6 @@ def _combine_ep_shardmap(out_buf, meta, rt: Runtime, t: int, d: int, e: int):
 
     out_buf: (E, B, C, D) sharded (experts->model, batch on B);
     meta arrays: (B, T*k) replicated over model."""
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     mesh = rt.mesh
@@ -155,11 +154,11 @@ def _combine_ep_shardmap(out_buf, meta, rt: Runtime, t: int, d: int, e: int):
         part = jax.vmap(one_row, in_axes=(1, 0, 0, 0, 0))(bufl, s_eid, rankc, tok, w)
         return jax.lax.psum(part, "model")
 
-    fn = shard_map(
+    fn = jax.shard_map(
         local_combine, mesh=mesh,
         in_specs=(P("model", batch_ax), P(batch_ax), P(batch_ax),
                   P(batch_ax), P(batch_ax)),
         out_specs=P(batch_ax),
-        check_rep=False)
+        check_vma=False)
     s_eid, rankc, tok, w = meta
     return fn(out_buf, s_eid, rankc, tok, w)

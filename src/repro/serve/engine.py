@@ -391,6 +391,7 @@ class ServeEngine:
         else:
             self.draft_cache = None
         self._cache_nbytes = self.cache_bytes  # fixed for the engine's life
+        self._paths = self._resolved_paths()
         self.pos = np.zeros(slots, dtype=np.int32)  # next write index per slot
         self.active: list[Optional[Request]] = [None] * slots
         self._next_tok = np.zeros(slots, dtype=np.int32)
@@ -453,6 +454,28 @@ class ServeEngine:
                         cl, cfg.resolved_head_dim, kvh, batch=slots,
                         g=max(1, getattr(cfg, "num_heads", kvh) // kvh),
                         q_width=self._spec_k + 1)
+
+    def _resolved_paths(self) -> dict:
+        """What the ``backend`` knob resolved to on this device: the
+        quantized matmuls' implementation(s) and the quantized-cache
+        attention's (``"pallas"``/``"ref"``), so a run can prove which
+        code served it."""
+        from repro.core.qlinear import resolve_backend
+        from repro.core.quantize import QTensor
+        from repro.kernels.attn_decode import resolve_attn_path
+
+        backend = "pallas" if self.rt.use_kernel else self.rt.backend
+        fmts = {leaf.meta.fmt for leaf in jax.tree.leaves(
+            self.params, is_leaf=lambda x: isinstance(x, QTensor))
+            if isinstance(leaf, QTensor)}
+        mm = sorted({resolve_backend(backend, f, self.rt.quant_mode)
+                     for f in fmts})
+        attn = None
+        if not self.cfg.attention_free:
+            attn = (resolve_attn_path(self.rt.backend,
+                                      self.cfg.resolved_head_dim)
+                    if self.rt.kv_quant else "ref")
+        return {"matmul_path": "+".join(mm) or "ref", "attn_path": attn}
 
     @property
     def temperature(self) -> float:
@@ -1506,6 +1529,7 @@ class ServeEngine:
             "shed_policy": self.shed_policy,
             # --- compute-path knobs (which numeric paths served this run) ---
             "backend": self.rt.backend,
+            **self._paths,
             "kv_quant": self.rt.kv_quant,
             "act_quant": self.rt.act_quant,
             "max_concurrent": self.max_concurrent,

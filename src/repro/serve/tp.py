@@ -43,7 +43,6 @@ from typing import Any, Callable, Optional
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.core.qlinear import qmatmul
@@ -261,9 +260,9 @@ def tp_qmatmul(x: jax.Array, qt: QTensor, rules: R.Rules, *, mode: str,
                        act_quant=act_quant)
 
     out_spec = P(*([None] * (x.ndim - 1) + ["model"]))
-    fn = shard_map(local_fn, mesh=mesh,
-                   in_specs=(P(), _qdata_specs(qt, msize)),
-                   out_specs=out_spec, check_rep=False)
+    fn = jax.shard_map(local_fn, mesh=mesh,
+                       in_specs=(P(), _qdata_specs(qt, msize)),
+                       out_specs=out_spec, check_vma=False)
     return fn(x, qt)
 
 
@@ -296,12 +295,12 @@ def tp_decode_attn_q8(q, cache, k_tok, v_tok, kv_len, rules: R.Rules, *,
         # block axis, which is unsharded; each shard gathers its own heads.
         cache_spec["table"] = P(None, None)
         cache_arg["table"] = cache["table"]
-    fn = shard_map(
+    fn = jax.shard_map(
         lambda q_, c_, kt_, vt_, kl_: decode_attn_q8(
             q_, c_, kt_, vt_, kl_, backend=backend, tt=tt),
         mesh=mesh,
         in_specs=(hq, cache_spec, (hc, hc), (hc, hc), P(None)),
-        out_specs=hq, check_rep=False)
+        out_specs=hq, check_vma=False)
     return fn(q, cache_arg, k_tok, v_tok, kv_len)
 
 
@@ -319,10 +318,10 @@ def tp_prefill_attn_q8(q, cache, kv_len, q_offset, rules: R.Rules, *,
     if "table" in cache:
         cache_spec["table"] = P(None, None)
         cache_arg["table"] = cache["table"]
-    fn = shard_map(
+    fn = jax.shard_map(
         lambda q_, c_, kl_, off_: prefill_attn_q8(
             q_, c_, kl_, off_, backend=backend, tq=tq, tt=tt),
         mesh=mesh,
         in_specs=(hq, cache_spec, P(None), P(None)),
-        out_specs=hq, check_rep=False)
+        out_specs=hq, check_vma=False)
     return fn(q, cache_arg, kv_len, q_offset)

@@ -79,9 +79,7 @@ def compressed_pod_allreduce(grads, error_buf, mesh, *, axis: str = "pod"):
         new_e = jax.tree.map(lambda o: o[1], out, is_leaf=lambda x: isinstance(x, tuple))
         return new_g, new_e
 
-    from jax.experimental.shard_map import shard_map
-
-    fn = shard_map(body, mesh=mesh,
-                   in_specs=(P(axis), P(axis)), out_specs=(P(axis), P(axis)),
-                   check_rep=False)
+    fn = jax.shard_map(body, mesh=mesh,
+                       in_specs=(P(axis), P(axis)),
+                       out_specs=(P(axis), P(axis)), check_vma=False)
     return fn(grads, error_buf)

@@ -41,6 +41,21 @@ def golden_requests(vocab):
     return reqs
 
 
+def _store(path, streams):
+    """Write ``streams`` under this process's threefry bit layout (the
+    weights, hence the streams, differ per layout; tests/_prng.py), keeping
+    the other layout's entry."""
+    layout = ("partitionable" if jax.config.jax_threefry_partitionable
+              else "legacy")
+    doc = {}
+    if os.path.exists(path):
+        with open(path) as f:
+            doc = json.load(f)
+    doc[layout] = streams
+    with open(path, "w") as f:
+        json.dump(doc, f, indent=1, sort_keys=True)
+
+
 def main():
     cfg = reduced(get_config("smollm-135m"))
     params = lm.init_params(jax.random.PRNGKey(0), cfg)
@@ -49,8 +64,7 @@ def main():
     done = eng.run(golden_requests(cfg.vocab_size))
     streams = {str(r.rid): [int(t) for t in r.out] for r in done}
     path = os.path.join(os.path.dirname(__file__), "paged_dense_streams.json")
-    with open(path, "w") as f:
-        json.dump(streams, f, indent=1, sort_keys=True)
+    _store(path, streams)
     print(f"wrote {path}: "
           f"{sum(len(v) for v in streams.values())} tokens over "
           f"{len(streams)} streams")

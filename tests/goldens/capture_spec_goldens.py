@@ -63,6 +63,21 @@ def capture(params, cfg, **engine_kw):
     return {str(r.rid): [int(t) for t in r.out] for r in done}
 
 
+def _store(path, streams):
+    """Write ``streams`` under this process's threefry bit layout (the
+    weights, hence the streams, differ per layout; tests/_prng.py), keeping
+    the other layout's entry."""
+    layout = ("partitionable" if jax.config.jax_threefry_partitionable
+              else "legacy")
+    doc = {}
+    if os.path.exists(path):
+        with open(path) as f:
+            doc = json.load(f)
+    doc[layout] = streams
+    with open(path, "w") as f:
+        json.dump(doc, f, indent=1, sort_keys=True)
+
+
 def main():
     cfg = reduced(get_config("smollm-135m"))
     params = lm.init_params(jax.random.PRNGKey(0), cfg)
@@ -78,8 +93,7 @@ def main():
                             paged=True),
     }
     path = os.path.join(os.path.dirname(__file__), "spec_decode_streams.json")
-    with open(path, "w") as f:
-        json.dump(doc, f, indent=1, sort_keys=True)
+    _store(path, doc)
     n = sum(len(v) for layout in doc.values() for v in layout.values())
     print(f"wrote {path}: {n} tokens over "
           f"{sum(len(v) for v in doc.values())} streams x {len(doc)} layouts")

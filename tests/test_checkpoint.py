@@ -56,9 +56,10 @@ def test_restore_missing_raises(tmp_path):
 def test_elastic_restore_with_shardings(tmp_path):
     """Restore onto explicit (single-device) shardings — the elastic path."""
     from jax.sharding import NamedSharding, PartitionSpec as P
+    from repro.launch.mesh import make_mesh
     d = str(tmp_path)
     ckpt.save(d, 1, tree())
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = make_mesh((1,), ("data",))
     sh = jax.tree.map(lambda _: NamedSharding(mesh, P()), tree())
     restored, _ = ckpt.restore(d, tree(), shardings=sh)
     assert restored["a"].sharding == NamedSharding(mesh, P())

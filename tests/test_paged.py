@@ -11,6 +11,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from _prng import prng_layout
 
 from repro.configs.base import get_config, reduced
 from repro.serve import kv_quant
@@ -46,7 +47,7 @@ def _load_golden_module():
 golden_requests = _load_golden_module().golden_requests
 
 with open(os.path.join(_GOLDEN_DIR, "paged_dense_streams.json")) as _f:
-    GOLDEN_STREAMS = json.load(_f)
+    GOLDEN_STREAMS = json.load(_f)[prng_layout()]
 
 
 @pytest.fixture(scope="module")

@@ -269,12 +269,13 @@ def test_ckpt_restore_shardings_align_past_qtensor(tmp_path, rng):
     """Shardings stay paired with their template leaves even when an
     earlier leaf is a QTensor (whose data dict spans several arrays)."""
     from jax.sharding import NamedSharding, PartitionSpec as P
+    from repro.launch.mesh import make_mesh
 
     d = str(tmp_path)
     tree = {"a_q": formats.quantize(heavy_tailed(rng), "itq3_s"),
             "b": jnp.arange(6, dtype=jnp.float32)}
     ckpt.save(d, 1, tree)
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = make_mesh((1,), ("data",))
     sh = jax.tree.map(lambda _: NamedSharding(mesh, P()), tree)
     restored, _ = ckpt.restore(d, tree, shardings=sh)
     assert isinstance(restored["a_q"], QTensor)

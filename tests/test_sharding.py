@@ -78,11 +78,12 @@ def test_param_pspecs_cover_tree():
 MULTIDEV_SCRIPT = textwrap.dedent("""
     import jax, jax.numpy as jnp, numpy as np
     from repro.configs.base import get_config, reduced
+    from repro.launch.mesh import make_mesh
     from repro.launch.train import build_trainer
     from repro.train import loop as tl
     from repro.data.pipeline import SyntheticCorpus
 
-    mesh = jax.make_mesh((4, 2), ("data", "model"))
+    mesh = make_mesh((4, 2), ("data", "model"))
     cfg = reduced(get_config("qwen1.5-0.5b"))
     jitted, shardings, rules = build_trainer(cfg, mesh, total_steps=4)
     with mesh:
@@ -111,6 +112,7 @@ SINGLE_VS_MULTI = textwrap.dedent("""
     from repro.configs.base import get_config, reduced
     from repro.models import lm
     from repro.models.layers import Runtime
+    from repro.launch.mesh import make_mesh
     from repro.sharding import rules as R
     from jax.sharding import NamedSharding, PartitionSpec as P
 
@@ -121,7 +123,7 @@ SINGLE_VS_MULTI = textwrap.dedent("""
     rt0 = Runtime(compute_dtype=jnp.float32, capacity_factor=8.0)
     base, _, _ = lm.forward(params, toks, rt0, cfg)
 
-    mesh = jax.make_mesh((4, 2), ("data", "model"))
+    mesh = make_mesh((4, 2), ("data", "model"))
     rules = R.make_rules(mesh, cfg)
     rt = Runtime(compute_dtype=jnp.float32, capacity_factor=8.0,
                  rules=rules, mesh=mesh)

@@ -25,6 +25,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from _prng import prng_layout
 
 from repro.configs.base import get_config, reduced
 from repro.models import lm
@@ -65,7 +66,7 @@ def _load_golden_module():
 golden_requests = _load_golden_module().golden_requests
 
 with open(os.path.join(_GOLDEN_DIR, "spec_decode_streams.json")) as _f:
-    GOLDENS = json.load(_f)
+    GOLDENS = json.load(_f)[prng_layout()]
 
 
 @pytest.fixture(scope="module")

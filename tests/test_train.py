@@ -96,9 +96,10 @@ def test_compressed_allreduce_error_feedback():
 
 SHARDMAP_COMPRESS = """
 import jax, jax.numpy as jnp, numpy as np
+from repro.launch.mesh import make_mesh
 from repro.train.grad import compressed_pod_allreduce, zeros_error_buf
 
-mesh = jax.make_mesh((2, 4), ("pod", "data"))
+mesh = make_mesh((2, 4), ("pod", "data"))
 rng = np.random.default_rng(0)
 g = {"w": jnp.asarray(rng.normal(size=(2, 64)), jnp.float32)}  # per-pod partials
 e = {"w": jnp.zeros((2, 64), jnp.float32)}

@@ -569,6 +569,19 @@ def paged_to_dense(cache: dict) -> dict:
     return {key: g(cache[key]) for key in ("k", "v", "k_scale", "v_scale")}
 
 
+def _row_planes(cache: dict, rows: int, hd: int) -> tuple:
+    """The cache's (k, k_scale, v, v_scale) planes as the kernels' row
+    operands: codes (rows, T, HD), scales (rows, T), where a row is one
+    (slot, kv head) of a dense cache or one (block, kv head) of the paged
+    pool. Named ``kv_cache`` in the compiled program, where laying the
+    planes out for the kernel may copy them."""
+    with jax.named_scope("kv_cache"):
+        return (cache["k"].reshape(rows, -1, hd),
+                cache["k_scale"].reshape(rows, -1),
+                cache["v"].reshape(rows, -1, hd),
+                cache["v_scale"].reshape(rows, -1))
+
+
 def decode_attn_q8(
     q: jax.Array,            # (B, KV, G, 1, HD) UNROTATED queries
     cache: dict,             # {"k","v": int8 (B,KV,T,HD); "k_scale","v_scale": (B,KV,T,1)}
@@ -619,20 +632,14 @@ def decode_attn_q8(
             nb, _, bs, _ = cache["k"].shape
             pool_rows = nb * kv
             acc, m, l = attn_decode_q8_pallas(
-                q_rot.reshape(r, g, hd),
-                cache["k"].reshape(pool_rows, bs, hd),
-                cache["k_scale"].reshape(pool_rows, bs),
-                cache["v"].reshape(pool_rows, bs, hd),
-                cache["v_scale"].reshape(pool_rows, bs),
+                q_rot.reshape(r, g, hd), *_row_planes(cache, pool_rows, hd),
                 jnp.broadcast_to(kv_len[:, None], (b, kv)).reshape(r),
                 paged_row_table(cache["table"], kv),
                 sm_scale=sm_scale, tt=tt, interpret=interpret,
                 early_exit=early_exit, block_size=bs)
         else:
             acc, m, l = attn_decode_q8_pallas(
-                q_rot.reshape(r, g, hd),
-                cache["k"].reshape(r, -1, hd), cache["k_scale"].reshape(r, -1),
-                cache["v"].reshape(r, -1, hd), cache["v_scale"].reshape(r, -1),
+                q_rot.reshape(r, g, hd), *_row_planes(cache, r, hd),
                 jnp.broadcast_to(kv_len[:, None], (b, kv)).reshape(r),
                 sm_scale=sm_scale, tt=tt, interpret=interpret,
                 early_exit=early_exit)
@@ -718,10 +725,7 @@ def prefill_attn_q8(
             pool_rows = nb * kv
             acc, m, l = attn_q8_pallas(
                 q_rot.reshape(r, tq_total, g, hd),
-                cache["k"].reshape(pool_rows, bs, hd),
-                cache["k_scale"].reshape(pool_rows, bs),
-                cache["v"].reshape(pool_rows, bs, hd),
-                cache["v_scale"].reshape(pool_rows, bs),
+                *_row_planes(cache, pool_rows, hd),
                 jnp.broadcast_to(kv_len[:, None], (b, kv)).reshape(r),
                 jnp.broadcast_to(q_offset[:, None], (b, kv)).reshape(r),
                 paged_row_table(cache["table"], kv),
@@ -729,9 +733,7 @@ def prefill_attn_q8(
                 interpret=interpret, early_exit=early_exit, block_size=bs)
         else:
             acc, m, l = attn_q8_pallas(
-                q_rot.reshape(r, tq_total, g, hd),
-                cache["k"].reshape(r, -1, hd), cache["k_scale"].reshape(r, -1),
-                cache["v"].reshape(r, -1, hd), cache["v_scale"].reshape(r, -1),
+                q_rot.reshape(r, tq_total, g, hd), *_row_planes(cache, r, hd),
                 jnp.broadcast_to(kv_len[:, None], (b, kv)).reshape(r),
                 jnp.broadcast_to(q_offset[:, None], (b, kv)).reshape(r),
                 sm_scale=sm_scale, causal=True, tq=tq, tt=tt,

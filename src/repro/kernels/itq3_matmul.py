@@ -103,6 +103,7 @@ def lane_tile(tn: int, n: int, *, interpret: bool) -> int:
     return tn
 
 
+@jax.named_scope("itq3_planes")
 def kernel_planes(tn: int, plane2, plane1, scales, zps):
     """Stored output-major operands -> the kernels' K-major lane-dense
     layout, with N zero-padded to a multiple of ``tn``:
@@ -115,7 +116,8 @@ def kernel_planes(tn: int, plane2, plane1, scales, zps):
     Every kernel block is then ``(1, rows, TN)`` with ``rows`` the whole
     second-minor dim and TN on the lanes, which the TPU compiler accepts
     for any TN that is a lane multiple (or the whole padded N). The
-    transpose runs in XLA on every call, outside the kernel."""
+    transpose runs in XLA on every call, outside the kernel, under the
+    name ``itq3_planes`` in the compiled program."""
     if scales.ndim == 2:
         scales = scales[..., None]
     ops = (plane2, plane1, scales.astype(jnp.float32),

@@ -402,23 +402,25 @@ def attention_apply(
                 return pool.at[blk, :, off, :].set(
                     jnp.swapaxes(vals, 1, 2).astype(pool.dtype))
 
-            new_cache = {"k": scat(cache["k"], kq),
-                         "v": scat(cache["v"], vq),
-                         "k_scale": scat(cache["k_scale"], ks),
-                         "v_scale": scat(cache["v_scale"], vs)}
+            with jax.named_scope("kv_cache"):
+                new_cache = {"k": scat(cache["k"], kq),
+                             "v": scat(cache["v"], vq),
+                             "k_scale": scat(cache["k_scale"], ks),
+                             "v_scale": scat(cache["v_scale"], vs)}
             read_cache = dict(new_cache, table=tbl)
         else:
             upd = jax.vmap(partial(jax.lax.dynamic_update_slice_in_dim, axis=1))
-            ck = upd(cache["k"], kq, pos_vec)
-            cks = upd(cache["k_scale"], ks.astype(cache["k_scale"].dtype),
-                      pos_vec)
-            cv = upd(cache["v"], vq, pos_vec)
-            cvs = upd(cache["v_scale"], vs.astype(cache["v_scale"].dtype),
-                      pos_vec)
-            ck = shard_hint(ck, rt, "batch", "kv_heads", "kv_seq", None)
-            cv = shard_hint(cv, rt, "batch", "kv_heads", "kv_seq", None)
-            cks = shard_hint(cks, rt, "batch", "kv_heads", "kv_seq", None)
-            cvs = shard_hint(cvs, rt, "batch", "kv_heads", "kv_seq", None)
+            with jax.named_scope("kv_cache"):
+                ck = upd(cache["k"], kq, pos_vec)
+                cks = upd(cache["k_scale"], ks.astype(cache["k_scale"].dtype),
+                          pos_vec)
+                cv = upd(cache["v"], vq, pos_vec)
+                cvs = upd(cache["v_scale"], vs.astype(cache["v_scale"].dtype),
+                          pos_vec)
+                ck = shard_hint(ck, rt, "batch", "kv_heads", "kv_seq", None)
+                cv = shard_hint(cv, rt, "batch", "kv_heads", "kv_seq", None)
+                cks = shard_hint(cks, rt, "batch", "kv_heads", "kv_seq", None)
+                cvs = shard_hint(cvs, rt, "batch", "kv_heads", "kv_seq", None)
             new_cache = {"k": ck, "v": cv, "k_scale": cks, "v_scale": cvs}
             read_cache = new_cache
         if t == 1:
@@ -444,10 +446,11 @@ def attention_apply(
         return dense(out, p["wo"], rt), new_cache
     elif cache is not None:
         upd = jax.vmap(partial(jax.lax.dynamic_update_slice_in_dim, axis=1))
-        ck = upd(cache["k"], k.astype(cache["k"].dtype), pos_vec)
-        cv = upd(cache["v"], v.astype(cache["v"].dtype), pos_vec)
-        ck = shard_hint(ck, rt, "batch", "kv_heads", "kv_seq", None)
-        cv = shard_hint(cv, rt, "batch", "kv_heads", "kv_seq", None)
+        with jax.named_scope("kv_cache"):
+            ck = upd(cache["k"], k.astype(cache["k"].dtype), pos_vec)
+            cv = upd(cache["v"], v.astype(cache["v"].dtype), pos_vec)
+            ck = shard_hint(ck, rt, "batch", "kv_heads", "kv_seq", None)
+            cv = shard_hint(cv, rt, "batch", "kv_heads", "kv_seq", None)
         new_cache = {"k": ck, "v": cv}
         k, v = ck, cv
         kv_len = pos_vec + t

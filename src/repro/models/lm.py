@@ -17,6 +17,14 @@ layer body, not 94):
 
 The serving cache is a pytree matching the family: attention KV, Mamba2
 (ssm, conv) state, RWKV6 (wkv, shift) state, or a mix.
+
+Compiled programs carry ``jax.named_scope`` names in their HLO ``op_name``
+metadata, so a device trace can put each operation down to a part of the
+model: ``embed``; per layer ``attn`` (norm, QKV, rope, the attention
+kernel, out projection), ``mlp`` and ``kv_cache`` (the stacked cache's
+per-layer slice and token write, and the pool's planes laid out for the
+kernel); ``head``; the engine's ``sample``; and ``itq3_planes``, the
+ITQ3_S planes laid out for the kernels (``kernels/itq3_matmul.py``).
 """
 from __future__ import annotations
 
@@ -185,29 +193,31 @@ def _residual(x, rt):
 
 def _dense_layer_apply(lp, x, rt, cfg, *, cache, pos, memory=None, causal=True,
                        token_cache=False):
-    h, new_kv = attention_apply(
-        lp["attn"], norm_apply(lp["ln1"], x, cfg.norm), rt, cfg,
-        causal=causal, cache=None if cache is None else cache["attn"], pos=pos,
-        token_cache=token_cache)
-    x = _residual(x + h, rt)
-    aux = jnp.zeros((), jnp.float32)
-    new_cache = None
-    if "xattn" in lp:
-        xc, new_xkv = attention_apply(
-            lp["xattn"], norm_apply(lp["ln_x"], x, cfg.norm), rt, cfg,
-            cross=True, memory=memory,
-            cache=None if cache is None else cache.get("xattn"))
-        x = _residual(x + xc, rt)
-        if cache is not None:
-            new_cache = {"attn": new_kv, "xattn": new_xkv}
-    elif cache is not None:
-        new_cache = {"attn": new_kv}
-    hn = norm_apply(lp["ln2"], x, cfg.norm)
-    if "moe" in lp:
-        m, aux = moe_mod.moe_apply(lp["moe"], hn, rt, cfg)
-    else:
-        m = mlp_apply(lp["mlp"], hn, rt, cfg.activation)
-    return _residual(x + m, rt), new_cache, aux
+    with jax.named_scope("attn"):
+        h, new_kv = attention_apply(
+            lp["attn"], norm_apply(lp["ln1"], x, cfg.norm), rt, cfg,
+            causal=causal, cache=None if cache is None else cache["attn"],
+            pos=pos, token_cache=token_cache)
+        x = _residual(x + h, rt)
+        new_cache = None
+        if "xattn" in lp:
+            xc, new_xkv = attention_apply(
+                lp["xattn"], norm_apply(lp["ln_x"], x, cfg.norm), rt, cfg,
+                cross=True, memory=memory,
+                cache=None if cache is None else cache.get("xattn"))
+            x = _residual(x + xc, rt)
+            if cache is not None:
+                new_cache = {"attn": new_kv, "xattn": new_xkv}
+        elif cache is not None:
+            new_cache = {"attn": new_kv}
+    with jax.named_scope("mlp"):
+        aux = jnp.zeros((), jnp.float32)
+        hn = norm_apply(lp["ln2"], x, cfg.norm)
+        if "moe" in lp:
+            m, aux = moe_mod.moe_apply(lp["moe"], hn, rt, cfg)
+        else:
+            m = mlp_apply(lp["mlp"], hn, rt, cfg.activation)
+        return _residual(x + m, rt), new_cache, aux
 
 
 def _maybe_remat(body, rt):
@@ -334,8 +344,10 @@ def _run_decoder_token(params, x, rt, cfg, *, cache, pos):
 
     def body(carry, inp):
         xc, cdict, i = carry
-        layer_attn = {lk: jax.lax.dynamic_index_in_dim(cdict[lk], i, 0, False)
-                      for lk in leaf_keys}
+        with jax.named_scope("kv_cache"):
+            layer_attn = {lk: jax.lax.dynamic_index_in_dim(cdict[lk], i, 0,
+                                                           False)
+                          for lk in leaf_keys}
         if tbl is not None:
             layer_attn["table"] = tbl
         if has_x:
@@ -346,14 +358,15 @@ def _run_decoder_token(params, x, rt, cfg, *, cache, pos):
             layer_cache = {"attn": layer_attn}
         xnew, cnew, aux = _dense_layer_apply(
             lp, xc, rt, cfg, cache=layer_cache, pos=pos_vec, token_cache=True)
-        if tbl is not None:
-            cdict = {lk: _write_token_kv_paged(
-                cdict[lk], cnew["attn"][_TOK_KEYS[lk]], i, tbl, pos_vec)
-                for lk in leaf_keys}
-        else:
-            cdict = {lk: _write_token_kv(cdict[lk], cnew["attn"][_TOK_KEYS[lk]],
-                                         i, pos_vec)
-                     for lk in leaf_keys}
+        with jax.named_scope("kv_cache"):
+            if tbl is not None:
+                cdict = {lk: _write_token_kv_paged(
+                    cdict[lk], cnew["attn"][_TOK_KEYS[lk]], i, tbl, pos_vec)
+                    for lk in leaf_keys}
+            else:
+                cdict = {lk: _write_token_kv(
+                    cdict[lk], cnew["attn"][_TOK_KEYS[lk]], i, pos_vec)
+                    for lk in leaf_keys}
         return (xnew, cdict, i + 1), aux
 
     xs = (params["layers"], cache["xattn"]["k"], cache["xattn"]["v"]) if has_x \
@@ -463,6 +476,7 @@ def _run_hybrid(params, x, rt, cfg, *, cache, pos):
 # Public API: forward / decode_step
 # ===========================================================================
 
+@jax.named_scope("embed")
 def _embed(params, tokens, rt, cfg):
     table = params["embed"]
     if isinstance(table, QTensor):
@@ -497,6 +511,7 @@ def _head_weight(params, rt):
     return w
 
 
+@jax.named_scope("head")
 def _head(params, x, rt, cfg):
     x = norm_apply(params["ln_f"], x, cfg.norm)
     logits = dense(x, _head_weight(params, rt), rt)
@@ -550,10 +565,11 @@ def forward(
                                      memory=memory)
     if cfg.frontend and frontend_feats is not None and cfg.family != "audio":
         x = x[:, frontend_feats.shape[1]:]
-    if last_only:
-        x = x[:, -1:]
-    elif last_idx is not None:
-        x = x[jnp.arange(x.shape[0]), last_idx][:, None]
+    with jax.named_scope("head"):
+        if last_only:
+            x = x[:, -1:]
+        elif last_idx is not None:
+            x = x[jnp.arange(x.shape[0]), last_idx][:, None]
     return _head(params, x, rt, cfg), new_cache, aux
 
 

@@ -36,6 +36,13 @@ Hot-path discipline (the decode loop is the product):
   ``sample_on_host=True`` restores the pre-overhaul per-slot host argmax —
   kept as the measured baseline for benchmarks/serve_bench.py.
   ``host_syncs`` counts every transfer either way.
+* **Phase spans.** Each phase of a tick runs under a
+  ``jax.profiler.TraceAnnotation`` (``serve.admit`` with its child
+  ``serve.prefill_sync``, ``serve.decode_prep``, ``serve.decode_dispatch``,
+  ``serve.decode_sync``, ``serve.commit``), one span per phase, never per
+  slot; the compiled programs carry ``jax.named_scope`` names for the
+  model's parts (``models/lm.py``). With no profiler recording, a span
+  does next to nothing.
 * **Donated cache buffers.** The jitted prefill/decode donate the cache
   operand (``donate_argnums``), so XLA writes the new cache in place
   instead of functionally copying ~cache_bytes every step;
@@ -123,6 +130,7 @@ from typing import Any, Iterable, Iterator, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.models import lm
 from repro.models.layers import Runtime
@@ -407,7 +415,8 @@ class ServeEngine:
         # non-speculative engines)
         self._slot_draft_k = np.zeros(slots, np.int32)
         self._pending_events: list[StreamEvent] = []
-        # --- perf counters (read by benchmarks/serve_bench.py and tests) ---
+        # --- perf counters (read by the bench harness: decode_steps and
+        # stats(); by launch/serve.py and by tests) ---
         self.host_syncs = 0       # device->host transfers
         self.tokens_decoded = 0   # tokens emitted by step()
         self.decode_steps = 0     # jitted decode calls
@@ -581,8 +590,9 @@ class ServeEngine:
         # reports the in-band _POISONED sentinel instead of a token, so
         # quarantine costs zero extra host syncs; healthy rows pass through
         # untouched (batch rows are independent -> bit-identical streams)
-        ok = lm.finite_rows(last)
-        return jnp.where(ok, tok, _POISONED), new_cache
+        with jax.named_scope("sample"):
+            ok = lm.finite_rows(last)
+            return jnp.where(ok, tok, _POISONED), new_cache
 
     def _decode_logits_impl(self, params, cache, tokens, positions,
                             table=None):
@@ -916,7 +926,11 @@ class ServeEngine:
         if free and len(self.scheduler):
             wave = self._pop_wave(free, events)
             if wave:
-                events += self._admit_group(wave)
+                with TraceAnnotation(
+                        "serve.admit", rids=[r.rid for r in wave],
+                        bucket=self._bucket(max(len(r.prompt)
+                                                for r in wave))):
+                    events += self._admit_group(wave)
         if any(r is not None for r in self.active):
             events += self._step_events()
         return events
@@ -1142,7 +1156,8 @@ class ServeEngine:
             firsts = [int(jnp.argmax(last[g])) for g in range(len(group))]
             self.host_syncs += len(group)
         else:
-            firsts = np.asarray(tok)
+            with TraceAnnotation("serve.prefill_sync"):
+                firsts = np.asarray(tok)
             self.host_syncs += 1
         now = self._clock()
         events = []
@@ -1179,27 +1194,22 @@ class ServeEngine:
             return self._spec_step_events()
         if self.faults is not None:
             self.faults.before_decode(self)
-        events0: list[StreamEvent] = []
-        if self.paged:
-            # grow block chains for slots whose next write crosses a block
-            # boundary (preempting victims on a dry pool); exhaustion can
-            # finish slots, so re-check liveness before decoding
-            events0 = self._ensure_decode_blocks()
-            if not any(r is not None for r in self.active):
-                return events0
-        n_live = sum(r is not None for r in self.active)
-        self.max_concurrent = max(self.max_concurrent, n_live)
-        toks = jnp.asarray(self._next_tok[:, None])
-        positions = jnp.asarray(self.pos)
-        table = jnp.asarray(self._table) if self.paged else None
-        probe = jax.tree.leaves(self.cache)
-        if self.sample_on_host:
-            logits, self.cache = self._jit_decode_logits(
-                self.params, self.cache, toks, positions, table)
-            tok_np = None
-        else:
+        with TraceAnnotation("serve.decode_prep"):
+            events0: list[StreamEvent] = []
+            if self.paged:
+                # grow block chains for slots whose next write crosses a
+                # block boundary (preempting victims on a dry pool);
+                # exhaustion can finish slots, so re-check liveness before
+                # decoding
+                events0 = self._ensure_decode_blocks()
+                if not any(r is not None for r in self.active):
+                    return events0
             live = [s for s, r in enumerate(self.active) if r is not None]
-            if all(self._temp[s] <= 0 for s in live):
+            self.max_concurrent = max(self.max_concurrent, len(live))
+            toks = jnp.asarray(self._next_tok[:, None])
+            positions = jnp.asarray(self.pos)
+            table = jnp.asarray(self._table) if self.paged else None
+            if self.sample_on_host or all(self._temp[s] <= 0 for s in live):
                 keys = gen = temp = top_k = top_p = None  # argmax-only trace
             else:
                 gen = jnp.asarray([len(r.out) if r is not None else 0
@@ -1210,34 +1220,46 @@ class ServeEngine:
                 # them: a temperature-only batch shouldn't pay top_mask's
                 # full-vocab sort+cumsum every step
                 top_k, top_p = self._filter_vectors(self._top_k, self._top_p)
-            tok_dev, self.cache = self._jit_decode(
-                self.params, self.cache, toks, positions,
-                keys, gen, temp, top_k, top_p, table)
-            tok_np = np.asarray(tok_dev)  # THE step's one transfer
+            probe = jax.tree.leaves(self.cache)
+        with TraceAnnotation("serve.decode_dispatch"):
+            if self.sample_on_host:
+                logits, self.cache = self._jit_decode_logits(
+                    self.params, self.cache, toks, positions, table)
+            else:
+                tok_dev, self.cache = self._jit_decode(
+                    self.params, self.cache, toks, positions,
+                    keys, gen, temp, top_k, top_p, table)
+        tok_np = None
+        if not self.sample_on_host:
+            with TraceAnnotation("serve.decode_sync",
+                                 step=self.decode_steps + 1,
+                                 live=len(live)):
+                tok_np = np.asarray(tok_dev)  # THE step's one transfer
             self.host_syncs += 1
         self.decode_steps += 1
-        # EVERY leaf must donate — a partially-donated cache (some planes
-        # copied, e.g. mixed int8/fp16/fp32 leaves under kv_quant) still
-        # burns bandwidth and must show up in the counter
-        self.cache_donated = all(a.is_deleted() for a in probe)
-        if not self.cache_donated:  # functional copy happened: count it
-            self.cache_bytes_moved += self._cache_nbytes
-        if self.watchdog is not None:
-            now = self._clock()
-            self.stalled_steps += len(self.watchdog.failed(now))
-            self.watchdog.beat(0, self.decode_steps, now=now)
-        events = events0
-        for s, req in enumerate(self.active):
-            if req is None:
-                continue
-            if tok_np is None:
-                row = np.asarray(logits[s])  # one transfer per slot
-                self.host_syncs += 1
-                tok = _POISONED if not np.isfinite(row).all() \
-                    else int(np.argmax(row))
-            else:
-                tok = int(tok_np[s])
-            events += self._commit_slot(s, req, [tok])
+        with TraceAnnotation("serve.commit"):
+            # EVERY leaf must donate — a partially-donated cache (some
+            # planes copied, e.g. mixed int8/fp16/fp32 leaves under
+            # kv_quant) still burns bandwidth and must show up in the counter
+            self.cache_donated = all(a.is_deleted() for a in probe)
+            if not self.cache_donated:  # functional copy happened: count it
+                self.cache_bytes_moved += self._cache_nbytes
+            if self.watchdog is not None:
+                now = self._clock()
+                self.stalled_steps += len(self.watchdog.failed(now))
+                self.watchdog.beat(0, self.decode_steps, now=now)
+            events = events0
+            for s, req in enumerate(self.active):
+                if req is None:
+                    continue
+                if tok_np is None:
+                    row = np.asarray(logits[s])  # one transfer per slot
+                    self.host_syncs += 1
+                    tok = _POISONED if not np.isfinite(row).all() \
+                        else int(np.argmax(row))
+                else:
+                    tok = int(tok_np[s])
+                events += self._commit_slot(s, req, [tok])
         return events
 
     def _spec_step_events(self) -> list[StreamEvent]:
@@ -1568,6 +1590,7 @@ class ServeEngine:
         return out
 
 
+@jax.named_scope("sample")
 def _sample_slots(last, keys, gen, temp, top_k, top_p):
     """Per-slot sampling inside the jitted step. ``keys`` (G, 2) are the
     requests' BASE keys; each row folds in its own request-local token

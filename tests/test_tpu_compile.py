@@ -212,3 +212,53 @@ def test_qwen_decode_step_compiles(one_chip, monkeypatch):
     for name in ("itq3_matvec_pallas", "fwht_pallas", "attn_q8_pallas"):
         assert f"jit({name})" in text, name
     _assert_kernel(compiled)
+
+
+@pytest.mark.parametrize("program", ["decode", "prefill"])
+def test_served_programs_keep_names_and_carry_scopes(one_chip, monkeypatch,
+                                                     program):
+    """The engine's decode and prefill programs of a small model, compiled
+    for the chip with the kernels on: every model scope reaches the
+    op_name metadata, and the kernel and program names the benchmark's
+    trace reduction matches are still the instruction and module names."""
+    import re
+
+    import repro.kernels.ops as ops
+    from repro.configs.base import get_config, reduced
+    from repro.models import lm
+    from repro.models.layers import Runtime
+    from repro.serve.engine import ServeEngine
+    from repro.serve.quantized import quantize_params
+
+    cfg = reduced(get_config("smollm-135m"))
+    params = quantize_params(lm.init_params(jax.random.PRNGKey(0), cfg),
+                             "itq3_s")
+    rt = Runtime(compute_dtype=jnp.float32, backend="pallas", kv_quant=True)
+    eng = ServeEngine(params, cfg, slots=4, max_len=512, prompt_pad=256,
+                      rt=rt, paged=True, block_size=256, num_blocks=9)
+    # code that asks jax.default_backend() sees the CPU here: steer it
+    monkeypatch.setattr(ops, "auto_interpret", lambda: False)
+    place = lambda tree: jax.tree.map(
+        lambda a: _shape(one_chip, a.shape, a.dtype), tree)
+    ints = lambda *shape: _shape(one_chip, shape, jnp.int32)
+    maxb = eng._table.shape[1]
+    if program == "decode":
+        lowered = eng._jit_decode.lower(
+            place(eng.params), place(eng.cache), ints(4, 1), ints(4), None,
+            None, None, None, None, ints(4, maxb))
+        kernels = ("attn_q8_pallas", "itq3_matvec_pallas")
+    else:
+        lowered = eng._jit_prefill.lower(
+            place(eng.params), place(eng.cache), ints(1, 256), ints(1),
+            ints(1), ints(1), None, None, None, None, ints(1, maxb),
+            plen=256, fresh=True)
+        kernels = ("attn_q8_pallas", "itq3_matmul_pallas")
+    text = lowered.compile().as_text()
+    assert text.startswith(f"HloModule jit__{program}_impl")
+    for name in kernels:
+        assert re.search(rf"^\s+(ROOT )?%{name}(\.\d+)? = ", text, re.M), name
+    parts = {p for path in re.findall(r'op_name="([^"]*)"', text)
+             for p in re.split(r"[/;]", path)}
+    scopes = ("embed", "kv_cache", "attn", "mlp", "head", "sample",
+              "itq3_planes")
+    assert not [s for s in scopes if s not in parts]

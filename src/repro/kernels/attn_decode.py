@@ -17,14 +17,22 @@ the rotated domain, and leaves the single inverse FWHT for the caller —
 per query span undoes the rotation for every cached token at once. A full
 dequantized K/V buffer is never materialized anywhere.
 
-One kernel serves both serving regimes, dispatched by query width:
+Both serving regimes run one online-softmax step per (slot, kv head, key
+tile) (:func:`_online_step`), on two grids chosen by the operands' rank:
 
-* **decode** (``q_len == 1``): grid ``(R, 1, NT)`` — the TQ=1
-  specialization. The current token rides OUTSIDE the cache, so the kernel
-  runs causal-free over ``kv_len`` cached positions and returns the
+* **decode** (``q_len == 1``): grid ``(B, NT)``, one step per (slot, key
+  tile) with ALL the slot's kv heads in it: ``(KV, TT, HD)`` code blocks
+  and ``(KV, TT)`` scales read straight from the cache's own ``(B, KV, T,
+  HD)`` planes or the pool's ``(NB, KV, BS, HD)``, the query and outputs
+  ``(KV, G, HD)``, scratch ``(KV*G, HD)``. Inside a step each head runs
+  the same arithmetic in the same order as a row of the row grid, so the
+  results are bitwise equal; a head's key tile is too small to pay for a
+  grid step of its own. The current token rides OUTSIDE the cache, so the
+  kernel runs causal-free over ``kv_len`` cached positions and returns the
   UNNORMALIZED ``(acc, m, l)`` triple; :func:`decode_attn_q8` merges the
   encoded self-token term (one more online-softmax step) and normalizes.
-* **prefill** (``q_len > 1``): grid ``(R, NQ, NT)`` — a query-tile
+* **prefill** (``q_len > 1``, speculative verify spans included): the row
+  grid ``(R, NQ, NT)`` over ``R = B*KV`` rows — a query-tile
   dimension with key tiles innermost. The in-flight span's K/V codes are
   already written into the cache at ``q_offset..q_offset+q_len-1``, so the
   causal mask ``q_offset + qpos >= kpos`` inside the key-tile loop merges
@@ -34,8 +42,8 @@ One kernel serves both serving regimes, dispatched by query width:
   the cache buffer; :func:`prefill_attn_q8` normalizes and applies the one
   inverse FWHT per query span.
 
-Each grid row is one (batch, kv_head) pair with a running online-softmax
-state in VMEM scratch:
+Each row of the row grid is one (batch, kv_head) pair with a running
+online-softmax state in VMEM scratch (the decode grid keeps KV such states):
 
     m   (TQ*G, 1)   running max over key tiles
     l   (TQ*G, 1)   running denominator
@@ -56,16 +64,17 @@ backends share score/weight formulas exactly (scores from codes, V scale
 folded into the weight row), so greedy token streams are identical.
 
 **Paged layout** (serve/paged.py): the same kernels also read a BLOCK-POOL
-cache — K/V planes stored as ``(num_blocks*KV, block_size, HD)`` pooled
-rows instead of per-slot rows, with a per-row int32 block table mapping
-each slot's logical key tile to its pool row. The table rides as a THIRD
-scalar-prefetch operand, so the key-tile index map does exactly one more
-gather: ``row = table[i, tile // tiles_per_block]`` instead of ``row = i``.
-The kernel body, masks, and early exit are untouched (masks key on the
-LOGICAL grid position), so paged and dense attention are bitwise identical
-whenever the gathered blocks hold the same codes/scales — the property
-tests/test_paged.py pins. The jnp reference path gathers ``pool[table]``
-back into the dense per-slot view and reuses the dense reference math.
+cache — K/V planes stored as ``(num_blocks, KV, block_size, HD)``. The
+decode grid reads the slot's int32 block table ``(B, MAXB)`` as a scalar-
+prefetch operand, so its key-tile index map does exactly one more gather:
+``block = table[b, tile // tiles_per_block]`` instead of ``b``. The row
+grid (prefill) sees the pool as ``(num_blocks*KV, block_size, HD)`` rows
+and takes a per-row table from :func:`paged_row_table`. The kernel body,
+masks, and early exit are untouched (masks key on the LOGICAL grid
+position), so paged and dense attention are bitwise identical whenever the
+gathered blocks hold the same codes/scales — the property tests/test_paged.py
+pins. The jnp reference path gathers ``pool[table]`` back into the dense
+per-slot view and reuses the dense reference math.
 """
 from __future__ import annotations
 
@@ -89,6 +98,9 @@ __all__ = [
 
 DEFAULT_TT = 256  # key-tile width (tokens streamed per grid step)
 DEFAULT_TQ = 128  # query-tile width (prefill rows per grid step)
+# VMEM for the decode grid's K and V code blocks of all kv heads, double
+# buffered; v5e's default scoped VMEM is 16 MiB
+FOLD_VMEM_BUDGET = 8 * 1024 * 1024
 NEG_INF = -1e30
 ATTN_BACKENDS = ("auto", "ref", "pallas")
 
@@ -146,6 +158,29 @@ def _last_tile(limit, *, tt: int):
     return jnp.maximum((limit + tt - 1) // tt - 1, 0)
 
 
+def _online_step(q, kc, ks, vc, vs, valid, m_old, dn, acc, *, sm_scale):
+    """One key tile of the online softmax for one (slot, kv head): scores
+    straight from the K codes (``(Hq).(Hk) == q.k``) times the per-token
+    scale, masked, then the V scale folded into the weight row
+    (``(p * v_scale) @ v_codes``). q (rows, HD) f32; kc/vc (TT, HD) int8;
+    ks/vs (1, TT) f32; valid (1|rows, TT); m_old/dn (rows, 1) and acc
+    (rows, HD) the running state. Returns the new ``(m, dn, acc)``."""
+    s = jax.lax.dot_general(q, kc.astype(jnp.float32),
+                            (((1,), (1,)), ((), ())),
+                            preferred_element_type=jnp.float32)
+    s = s * (ks * sm_scale)  # (rows, TT) * (1, TT)
+    s = jnp.where(valid, s, NEG_INF)
+    m_new = jnp.maximum(m_old, jnp.max(s, axis=-1, keepdims=True))
+    alpha = jnp.exp(m_old - m_new)
+    p = jnp.exp(s - m_new)
+    p = jnp.where(valid, p, 0.0)  # NEG_INF - NEG_INF would leak exp(0)
+    dn = dn * alpha + jnp.sum(p, axis=-1, keepdims=True)
+    acc = acc * alpha + jax.lax.dot_general(
+        p * vs, vc.astype(jnp.float32), (((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32)
+    return m_new, dn, acc
+
+
 def _attn_q8_kernel(
     len_ref,  # (R,) int32 scalar-prefetch — valid cache length per row
     off_ref,  # (R,) int32 scalar-prefetch — absolute position of query 0
@@ -194,12 +229,6 @@ def _attn_q8_kernel(
         rows = tq * g
         hd = q_ref.shape[-1]
         q = q_ref[0].reshape(rows, hd)  # (TQ*G, HD) f32, already rotated
-        kc = kc_ref[0].astype(jnp.float32)  # (TT, HD)
-        # dequantize-free scores: (Hq).(Hk) == q.k, per-token scale on row
-        s = jax.lax.dot_general(q, kc, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32)
-        s = s * (ks_ref[0] * sm_scale)  # (rows, TT) * (1, TT)
-
         kpos = t * tt + jax.lax.broadcasted_iota(jnp.int32, (1, tt), 1)
         valid = kpos < len_ref[r]  # (1, TT)
         if causal:
@@ -207,23 +236,10 @@ def _attn_q8_kernel(
             # qt*TQ + i//g must not look past itself into the key tile
             qpos = (off_ref[r] + qt * tq
                     + jax.lax.broadcasted_iota(jnp.int32, (rows, 1), 0) // g)
-            valid_c = valid & (kpos <= qpos)  # (rows, TT)
-        else:
-            valid_c = valid
-        s = jnp.where(valid_c, s, NEG_INF)
-
-        m_old = mx_ref[...]  # (rows, 1)
-        m_new = jnp.maximum(m_old, jnp.max(s, axis=-1, keepdims=True))
-        alpha = jnp.exp(m_old - m_new)
-        p = jnp.exp(s - m_new)
-        p = jnp.where(valid_c, p, 0.0)  # NEG_INF - NEG_INF would leak exp(0)
-        mx_ref[...] = m_new
-        dn_ref[...] = dn_ref[...] * alpha + jnp.sum(p, axis=-1, keepdims=True)
-        # V dequant folded into the weight row: (p * v_scale) @ v_codes
-        pv = p * vs_ref[0]
-        acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
-            pv, vc_ref[0].astype(jnp.float32), (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+            valid = valid & (kpos <= qpos)  # (rows, TT)
+        mx_ref[...], dn_ref[...], acc_ref[...] = _online_step(
+            q, kc_ref[0], ks_ref[0], vc_ref[0], vs_ref[0], valid,
+            mx_ref[...], dn_ref[...], acc_ref[...], sm_scale=sm_scale)
 
     @pl.when(t == nt - 1)
     def _flush():
@@ -231,6 +247,64 @@ def _attn_q8_kernel(
         o_ref[...] = acc_ref[...].reshape(1, tq, g, hd)
         m_ref[...] = mx_ref[...].reshape(1, tq, g, 1)
         l_ref[...] = dn_ref[...].reshape(1, tq, g, 1)
+
+
+def _attn_q8_decode_kernel(
+    len_ref,  # (B,) int32 scalar-prefetch — valid cache length per slot
+    q_ref,    # (1, KV, G, HD) f32 — rotated queries of every kv head
+    kc_ref,   # (1, KV, TT, HD) int8 — K codes tile of every kv head
+    ks_ref,   # (1, KV, TT) f32 — K per-token scales
+    vc_ref,   # (1, KV, TT, HD) int8 — V codes tile
+    vs_ref,   # (1, KV, TT) f32 — V per-token scales
+    o_ref,    # (1, KV, G, HD) f32 — unnormalized weighted V sum
+    m_ref,    # (1, KV, G, 1) f32 — running max
+    l_ref,    # (1, KV, G, 1) f32 — running denominator
+    acc_ref,  # scratch (KV*G, HD) f32
+    mx_ref,   # scratch (KV*G, 1) f32
+    dn_ref,   # scratch (KV*G, 1) f32
+    *,
+    sm_scale: float,
+    g: int,
+    tt: int,
+    nt: int,
+    early_exit: bool,
+):
+    """Decode over one (slot, key tile) with every kv head of the slot in
+    the step: each head runs :func:`_online_step` on its own rows of the
+    scratch, in head order, exactly as a row of the row grid would."""
+    b = pl.program_id(0)
+    t = pl.program_id(1)
+    kv = q_ref.shape[1]
+
+    @pl.when(t == 0)
+    def _init():
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+        mx_ref[...] = jnp.full_like(mx_ref, NEG_INF)
+        dn_ref[...] = jnp.zeros_like(dn_ref)
+
+    # one kv_len a slot: every head of the step exits together
+    run = (t * tt < len_ref[b]) if early_exit else (t >= 0)
+
+    @pl.when(run)
+    def _update():
+        kpos = t * tt + jax.lax.broadcasted_iota(jnp.int32, (1, tt), 1)
+        valid = kpos < len_ref[b]  # (1, TT)
+        for h in range(kv):
+            rows = slice(h * g, (h + 1) * g)
+            (mx_ref[rows, :], dn_ref[rows, :],
+             acc_ref[rows, :]) = _online_step(
+                q_ref[0, h], kc_ref[0, h], ks_ref[0, h:h + 1, :],
+                vc_ref[0, h], vs_ref[0, h:h + 1, :], valid,
+                mx_ref[rows, :], dn_ref[rows, :], acc_ref[rows, :],
+                sm_scale=sm_scale)
+
+    @pl.when(t == nt - 1)
+    def _flush():
+        for h in range(kv):
+            rows = slice(h * g, (h + 1) * g)
+            o_ref[0, h] = acc_ref[rows, :]
+            m_ref[0, h] = mx_ref[rows, :]
+            l_ref[0, h] = dn_ref[rows, :]
 
 
 @functools.partial(jax.jit, static_argnames=("tq", "tt", "causal",
@@ -243,7 +317,7 @@ def attn_q8_pallas(
     v_codes: jax.Array,   # (R, T, HD) int8 — or (PR, BS, HD)
     v_scale: jax.Array,   # (R, T) f16/f32 — or (PR, BS)
     kv_len: jax.Array,    # (R,) int32 — valid cache positions per row
-    q_offset: jax.Array,  # (R,) int32 — absolute position of query 0
+    q_offset: jax.Array | None = None,  # (R,) int32 — position of query 0
     table: jax.Array | None = None,  # (R, MAXB) int32 pool-row block table
     *,
     sm_scale: float,
@@ -281,8 +355,27 @@ def attn_q8_pallas(
     Returns the UNNORMALIZED triple ``(acc (R, TQ, G, HD), m (R, TQ, G, 1),
     l (R, TQ, G, 1))`` so the caller chooses what to merge before
     normalizing (decode merges the in-flight token's self term; prefill,
-    whose span is already in the cache, just divides)."""
+    whose span is already in the cache, just divides).
+
+    **Slot planes.** Four-dimensional codes ``(B, KV, T, HD)`` (dense) or
+    ``(NB, KV, BS, HD)`` (the pool's own planes), with scales ``(B|NB, KV,
+    T|BS)`` and ``q_rot`` ``(B, KV, G, HD)``, take the decode grid
+    ``(B, NT)`` instead: one query position, causal-free (``causal`` must
+    be False; ``tq`` and ``q_offset`` are unused), ``kv_len`` and ``table``
+    per slot — ``table`` is then the per-slot pool-BLOCK table ``(B,
+    MAXB)``. Returns ``(acc (B, KV, G, HD), m, l (B, KV, G, 1))``, bitwise
+    equal to the row grid over the same data."""
+    if k_codes.ndim == 4:
+        if causal:
+            raise ValueError("slot planes take the causal-free decode grid; "
+                             "pass causal=False, or (rows, T, HD) planes")
+        return _attn_q8_slots(q_rot, k_codes, k_scale, v_codes, v_scale,
+                              kv_len, table, sm_scale=sm_scale, tt=tt,
+                              interpret=interpret, early_exit=early_exit,
+                              block_size=block_size)
     r, tq_total, g, hd = q_rot.shape
+    if q_offset is None:
+        q_offset = jnp.zeros((r,), jnp.int32)
     paged = table is not None
     if paged:
         if block_size is None:
@@ -390,6 +483,106 @@ def attn_q8_pallas(
     return out, m, l
 
 
+def _fold_tt(tt: int, kv: int, hd: int) -> int:
+    """Key-tile width for the decode grid: ``tt`` halved (while it stays a
+    multiple of 128, the scale block's lane rule) until the K and V code
+    blocks of all ``kv`` heads fit :data:`FOLD_VMEM_BUDGET` double-buffered.
+    An int8 ``(TT, HD)`` tile fills whole 128-lane rows in VMEM."""
+    lanes = -(-hd // 128) * 128
+    while tt % 256 == 0 and 2 * 2 * kv * tt * lanes > FOLD_VMEM_BUDGET:
+        tt //= 2
+    return tt
+
+
+def _attn_q8_slots(q_rot, k_codes, k_scale, v_codes, v_scale, kv_len, table,
+                   *, sm_scale, tt, interpret, early_exit, block_size):
+    """The decode grid ``(B, NT)`` over slot planes (see
+    :func:`attn_q8_pallas`): a step moves the ``(KV, TT, HD)`` code tiles
+    and ``(KV, TT)`` scales of every kv head of one slot."""
+    b, kv, g, hd = q_rot.shape
+    paged = table is not None
+    if paged:
+        if block_size is None:
+            raise ValueError("paged attention needs block_size with table")
+        bs = int(block_size)
+        if k_codes.shape[2] != bs:
+            raise ValueError(
+                f"pool planes must be (num_blocks, kv_heads, block_size, "
+                f"HD); got {k_codes.shape} for block_size {bs}")
+        tt = _fold_tt(math.gcd(max(1, min(tt, bs)), bs), kv, hd)
+        tpb = bs // tt  # key tiles per pool block
+        nt = table.shape[1] * tpb
+    else:
+        t = k_codes.shape[2]
+        tt = _fold_tt(max(1, min(tt, t)), kv, hd)
+        pad_t = (-t) % tt
+        if pad_t:
+            k_codes = jnp.pad(k_codes, ((0, 0), (0, 0), (0, pad_t), (0, 0)))
+            v_codes = jnp.pad(v_codes, ((0, 0), (0, 0), (0, pad_t), (0, 0)))
+            k_scale = jnp.pad(k_scale, ((0, 0), (0, 0), (0, pad_t)))
+            v_scale = jnp.pad(v_scale, ((0, 0), (0, 0), (0, pad_t)))
+        nt = k_codes.shape[2] // tt
+
+    def tile(i, ti, len_ref, *tbl):
+        if early_exit:
+            ti = jnp.minimum(ti, _last_tile(len_ref[i], tt=tt))
+        if paged:
+            # the slot's block table, read directly: (pool block, tile in it)
+            return tbl[0][i, ti // tpb], ti % tpb
+        return i, ti
+
+    def codes_map(i, ti, *refs):
+        blk, tj = tile(i, ti, *refs)
+        return (blk, 0, tj, 0)
+
+    def scale_map(i, ti, *refs):
+        blk, tj = tile(i, ti, *refs)
+        return (blk, 0, tj)
+
+    def q_map(i, ti, *refs):
+        return (i, 0, 0, 0)
+
+    kernel = functools.partial(_attn_q8_decode_kernel, sm_scale=sm_scale,
+                               g=g, tt=tt, nt=nt, early_exit=early_exit)
+    if paged:
+        kernel = functools.partial(_drop_slot_table_ref, kernel)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2 if paged else 1,  # kv_len[, table]
+        grid=(b, nt),
+        in_specs=[
+            pl.BlockSpec((1, kv, g, hd), q_map),
+            pl.BlockSpec((1, kv, tt, hd), codes_map),
+            pl.BlockSpec((1, kv, tt), scale_map),
+            pl.BlockSpec((1, kv, tt, hd), codes_map),
+            pl.BlockSpec((1, kv, tt), scale_map),
+        ],
+        out_specs=[
+            pl.BlockSpec((1, kv, g, hd), q_map),
+            pl.BlockSpec((1, kv, g, 1), q_map),
+            pl.BlockSpec((1, kv, g, 1), q_map),
+        ],
+        scratch_shapes=[
+            pltpu.VMEM((kv * g, hd), jnp.float32),
+            pltpu.VMEM((kv * g, 1), jnp.float32),
+            pltpu.VMEM((kv * g, 1), jnp.float32),
+        ],
+    )
+    scalars = [kv_len.astype(jnp.int32)]
+    if paged:
+        scalars.append(table.astype(jnp.int32))
+    return pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
+        out_shape=[
+            jax.ShapeDtypeStruct((b, kv, g, hd), jnp.float32),
+            jax.ShapeDtypeStruct((b, kv, g, 1), jnp.float32),
+            jax.ShapeDtypeStruct((b, kv, g, 1), jnp.float32),
+        ],
+        interpret=interpret,
+    )(*scalars, q_rot.astype(jnp.float32), k_codes,
+      k_scale.astype(jnp.float32), v_codes, v_scale.astype(jnp.float32))
+
+
 def _scale_rows(scale: jax.Array) -> jax.Array:
     """(R, T) per-token scales -> (R, 1, T) f32: a unit sublane axis lets
     the kernel tile the time axis as (1, 1, TT), which meets the TPU's
@@ -401,6 +594,11 @@ def _drop_table_ref(kernel, len_ref, off_ref, tbl_ref, *rest):
     """Adapter for the paged call: the block table is scalar-prefetch
     operand #3 (index maps read it) but the kernel body has no use for it."""
     return kernel(len_ref, off_ref, *rest)
+
+
+def _drop_slot_table_ref(kernel, len_ref, tbl_ref, *rest):
+    """The decode grid's counterpart of :func:`_drop_table_ref`."""
+    return kernel(len_ref, *rest)
 
 
 def attn_decode_q8_pallas(
@@ -423,14 +621,19 @@ def attn_decode_q8_pallas(
     does not yet contain the current token, so no in-span causality
     exists). Returns the unnormalized ``(acc (R, G, HD), m (R, G, 1),
     l (R, G, 1))`` triple — see :func:`decode_attn_q8` for the self-token
-    merge."""
-    r = q_rot.shape[0]
+    merge.
+
+    Slot planes (4-D codes, ``q_rot`` ``(B, KV, G, HD)``, ``kv_len`` and
+    the block ``table`` per slot) run the decode grid ``(B, NT)`` and
+    return ``(B, KV, G, ...)``; row planes run the row grid ``(R, 1,
+    NT)``, whose results the decode grid matches bitwise."""
+    slots = k_codes.ndim == 4
     acc, m, l = attn_q8_pallas(
-        q_rot[:, None], k_codes, k_scale, v_codes, v_scale, kv_len,
-        jnp.zeros((r,), jnp.int32), table, sm_scale=sm_scale, causal=False,
-        tq=1, tt=tt, interpret=interpret, early_exit=early_exit,
+        q_rot if slots else q_rot[:, None], k_codes, k_scale, v_codes,
+        v_scale, kv_len, None, table, sm_scale=sm_scale, causal=False, tq=1,
+        tt=tt, interpret=interpret, early_exit=early_exit,
         block_size=block_size)
-    return acc[:, 0], m[:, 0], l[:, 0]
+    return (acc, m, l) if slots else (acc[:, 0], m[:, 0], l[:, 0])
 
 
 def _merge_self_token(acc, m, l, s_self, v_self):
@@ -544,9 +747,10 @@ def prefill_attn_q8_ref(
 
 def paged_row_table(table: jax.Array, kv_heads: int) -> jax.Array:
     """Expand a per-slot pool-BLOCK table (B, MAXB) to the per-(b, kv_head)
-    pool-ROW table (B*KV, MAXB) the kernel's index maps consume: pooled
+    pool-ROW table (B*KV, MAXB) the row grid's index maps consume: pooled
     planes flatten (num_blocks, KV, ...) to row ``block*KV + head``, so the
-    head offset folds into the table once, outside the kernel."""
+    head offset folds into the table once, outside the kernel. Prefill
+    only: the decode grid reads the block table itself."""
     b, maxb = table.shape
     rows = (table[:, None, :] * kv_heads
             + jnp.arange(kv_heads, dtype=table.dtype)[None, :, None])
@@ -582,6 +786,16 @@ def _row_planes(cache: dict, rows: int, hd: int) -> tuple:
                 cache["v_scale"].reshape(rows, -1))
 
 
+def _slot_planes(cache: dict) -> tuple:
+    """The cache's (k, k_scale, v, v_scale) planes as the decode grid's
+    operands: codes as stored, ``(B, KV, T, HD)`` dense or the pool's own
+    ``(NB, KV, BS, HD)``; scales without their unit axis. Named
+    ``kv_cache`` in the compiled program, like :func:`_row_planes`."""
+    with jax.named_scope("kv_cache"):
+        return (cache["k"], cache["k_scale"][..., 0],
+                cache["v"], cache["v_scale"][..., 0])
+
+
 def decode_attn_q8(
     q: jax.Array,            # (B, KV, G, 1, HD) UNROTATED queries
     cache: dict,             # {"k","v": int8 (B,KV,T,HD); "k_scale","v_scale": (B,KV,T,1)}
@@ -602,11 +816,11 @@ def decode_attn_q8(
     the values every later step will read back — greedy streams match the
     dequantize-then-attend reference bit-for-decision.
 
-    A PAGED cache dict (extra ``"table"`` key; planes laid out
-    (num_blocks, KV, block_size, HD|1) — serve/paged.py) routes through the
-    same kernel with the block table as a third scalar-prefetch operand, or
-    through the dense reference over the gathered :func:`paged_to_dense`
-    view.
+    The kernel runs the decode grid ``(B, NT)`` over the cache's own
+    planes. A PAGED cache dict (extra ``"table"`` key; planes laid out
+    (num_blocks, KV, block_size, HD|1) — serve/paged.py) adds the slot's
+    block table as a second scalar-prefetch operand; the reference runs
+    over the gathered :func:`paged_to_dense` view.
 
     Returns (B, KV, G, 1, HD) f32."""
     from repro.kernels.ops import auto_interpret  # local: avoid import cycle
@@ -627,25 +841,11 @@ def decode_attn_q8(
             # kv heads); deterministic defaults in interpret mode
             from repro.kernels.autotune import get_attn_tiles
             _, tt = get_attn_tiles(cache_len, hd, kv, interpret=interpret)
-        r = b * kv
-        if paged:
-            nb, _, bs, _ = cache["k"].shape
-            pool_rows = nb * kv
-            acc, m, l = attn_decode_q8_pallas(
-                q_rot.reshape(r, g, hd), *_row_planes(cache, pool_rows, hd),
-                jnp.broadcast_to(kv_len[:, None], (b, kv)).reshape(r),
-                paged_row_table(cache["table"], kv),
-                sm_scale=sm_scale, tt=tt, interpret=interpret,
-                early_exit=early_exit, block_size=bs)
-        else:
-            acc, m, l = attn_decode_q8_pallas(
-                q_rot.reshape(r, g, hd), *_row_planes(cache, r, hd),
-                jnp.broadcast_to(kv_len[:, None], (b, kv)).reshape(r),
-                sm_scale=sm_scale, tt=tt, interpret=interpret,
-                early_exit=early_exit)
-        acc = acc.reshape(b, kv, g, hd)
-        m = m.reshape(b, kv, g, 1)
-        l = l.reshape(b, kv, g, 1)
+        acc, m, l = attn_decode_q8_pallas(
+            q_rot, *_slot_planes(cache), kv_len, cache.get("table"),
+            sm_scale=sm_scale, tt=tt, interpret=interpret,
+            early_exit=early_exit,
+            block_size=cache["k"].shape[2] if paged else None)
     else:
         dc = paged_to_dense(cache) if paged else cache
         acc, m, l = decode_attn_q8_ref(

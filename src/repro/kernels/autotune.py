@@ -344,20 +344,20 @@ def autotune_attn(t: int, head_dim: int, n_heads: int, *, batch: int = 4,
         return DEFAULT_TQ, DEFAULT_TT
 
     rng = np.random.default_rng(0)
-    r = batch * n_heads
     if decode:
-        tq_total = 1
-    elif q_width is not None:
-        tq_total = q_width
+        # decode runs the slot-plane grid: every kv head of a slot per step
+        lead, q_shape = (batch, n_heads), (batch, n_heads, g, head_dim)
     else:
-        tq_total = min(t, 512)
-    q = np.asarray(rng.normal(size=(r, tq_total, g, head_dim)), np.float32)
-    kc = rng.integers(-127, 128, size=(r, t, head_dim)).astype(np.int8)
-    vc = rng.integers(-127, 128, size=(r, t, head_dim)).astype(np.int8)
-    ks = np.abs(rng.normal(size=(r, t))).astype(np.float32) * 0.02
-    vs = np.abs(rng.normal(size=(r, t))).astype(np.float32) * 0.02
-    kv_len = np.full((r,), t, np.int32)
-    off = np.zeros((r,), np.int32)
+        tq_total = q_width if q_width is not None else min(t, 512)
+        lead = (batch * n_heads,)
+        q_shape = (lead[0], tq_total, g, head_dim)
+    q = np.asarray(rng.normal(size=q_shape), np.float32)
+    kc = rng.integers(-127, 128, size=lead + (t, head_dim)).astype(np.int8)
+    vc = rng.integers(-127, 128, size=lead + (t, head_dim)).astype(np.int8)
+    ks = np.abs(rng.normal(size=lead + (t,))).astype(np.float32) * 0.02
+    vs = np.abs(rng.normal(size=lead + (t,))).astype(np.float32) * 0.02
+    kv_len = np.full(lead[:1], t, np.int32)
+    off = None if decode else np.zeros(lead, np.int32)
 
     best, best_us = (DEFAULT_TQ, DEFAULT_TT), float("inf")
     for tq, tt in attn_candidates(t, head_dim, decode=decode,

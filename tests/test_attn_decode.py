@@ -34,6 +34,9 @@ def _quant_cache(rng, b, kv, t, hd):
 
 @pytest.mark.parametrize("b,kv,g,hd,t", [
     (2, 1, 4, 32, 48), (1, 3, 2, 64, 33), (2, 2, 1, 128, 17),
+    # the decode grid at the served models' head layouts: MHA (qwen),
+    # GQA 9 over 3 (smollm), and a 128-wide head
+    (3, 16, 1, 64, 40), (4, 3, 3, 64, 40), (2, 2, 4, 128, 40),
 ])
 def test_kernel_matches_ref_backend(rng, b, kv, g, hd, t):
     cache, _, _ = _quant_cache(rng, b, kv, t, hd)
@@ -518,6 +521,10 @@ def test_early_exit_bitwise_matches_full_loop_decode(rng):
     args = (q, cache["k"].reshape(r, t, hd), cache["k_scale"].reshape(r, t),
             cache["v"].reshape(r, t, hd), cache["v_scale"].reshape(r, t),
             jnp.asarray([5, 300, 640], jnp.int32))  # tiny, mid, full rows
+    # the same rows as slots of one kv head each (the lengths differ per
+    # row): the decode grid, one step per slot and key tile
+    slots = (q[:, None], args[1][:, None], args[2][:, None],
+             args[3][:, None], args[4][:, None], args[5])
     for tt in (64, 128, 256):
         full = ad.attn_decode_q8_pallas(*args, sm_scale=hd ** -0.5, tt=tt,
                                         interpret=True, early_exit=False)
@@ -525,6 +532,78 @@ def test_early_exit_bitwise_matches_full_loop_decode(rng):
                                         interpret=True, early_exit=True)
         for a, b_ in zip(full, fast):
             assert np.array_equal(np.asarray(a), np.asarray(b_)), tt
+        for ee in (False, True):
+            fold = ad.attn_decode_q8_pallas(*slots, sm_scale=hd ** -0.5,
+                                            tt=tt, interpret=True,
+                                            early_exit=ee)
+            for a, b_ in zip(full, fold):
+                assert np.array_equal(np.asarray(a),
+                                      np.asarray(b_).reshape(a.shape)), tt
+
+
+def _slot_and_row_operands(rng, b, kv, g, hd, bs, maxb, paged):
+    """One random quantized cache as the decode grid's slot planes and as
+    the row grid's rows (the pool's rows through ``paged_row_table`` when
+    paged), plus the dense view for the reference."""
+    t = maxb * bs
+    cache, _, _ = _quant_cache(rng, b, kv, t, hd)
+    q = jnp.asarray(rng.normal(size=(b, kv, g, hd)), jnp.float32)
+    if paged:
+        nb = b * maxb + 1
+        table = jnp.asarray(1 + rng.permutation(b * maxb).reshape(b, maxb),
+                            jnp.int32)
+        planes = {}
+        for key, leaf in cache.items():
+            x = leaf.reshape(b, kv, maxb, bs, -1)
+            x = jnp.swapaxes(x, 1, 2).reshape(b * maxb, kv, bs, -1)
+            pool = jnp.zeros((nb,) + x.shape[1:], leaf.dtype)
+            planes[key] = pool.at[table.reshape(-1)].set(x)
+        lead, width, rows_tbl = nb, bs, ad.paged_row_table(table, kv)
+    else:
+        planes, table, rows_tbl, lead, width = cache, None, None, b, t
+    slot = (q, planes["k"], planes["k_scale"][..., 0], planes["v"],
+            planes["v_scale"][..., 0])
+    rows = (q.reshape(b * kv, g, hd),
+            planes["k"].reshape(lead * kv, width, hd),
+            planes["k_scale"].reshape(lead * kv, width),
+            planes["v"].reshape(lead * kv, width, hd),
+            planes["v_scale"].reshape(lead * kv, width))
+    return cache, slot, table, rows, rows_tbl
+
+
+@pytest.mark.parametrize("paged", [False, True], ids=["dense", "paged"])
+@pytest.mark.parametrize("b,kv,g,hd", [
+    (3, 16, 1, 64), (4, 3, 3, 64), (2, 2, 4, 128),
+])
+def test_decode_grid_bitwise_matches_row_grid(rng, b, kv, g, hd, paged):
+    """The decode grid (a step per slot and key tile, every kv head in it)
+    is bitwise equal to the row grid (a step per slot, kv head and key
+    tile) over the same data, with and without the early exit, and close
+    to the jnp reference; kv_len 0, 1, mid-tile, a block boundary and the
+    full table."""
+    bs, maxb, tt = 16, 3, 8
+    cache, slot, table, rows, rows_tbl = _slot_and_row_operands(
+        rng, b, kv, g, hd, bs, maxb, paged)
+    lens = [0, 1, tt // 2, bs, maxb * bs]
+    kw = dict(sm_scale=hd ** -0.5, tt=tt, interpret=True,
+              block_size=bs if paged else None)
+    for start in range(0, len(lens), b):
+        kl = jnp.asarray([lens[(start + i) % len(lens)] for i in range(b)],
+                         jnp.int32)
+        want = ad.attn_decode_q8_pallas(*rows, jnp.repeat(kl, kv), rows_tbl,
+                                        **kw)
+        ref = ad.decode_attn_q8_ref(slot[0], cache["k"], cache["k_scale"],
+                                    cache["v"], cache["v_scale"], kl,
+                                    sm_scale=hd ** -0.5)
+        for ee in (False, True):
+            got = ad.attn_decode_q8_pallas(*slot, kl, table, early_exit=ee,
+                                           **kw)
+            for a, w, r in zip(got, want, ref):
+                assert a.shape == r.shape
+                assert np.array_equal(np.asarray(a).reshape(w.shape),
+                                      np.asarray(w)), (kl, ee)
+                np.testing.assert_allclose(np.asarray(a), np.asarray(r),
+                                           atol=1e-5, rtol=1e-5)
 
 
 def test_early_exit_bitwise_matches_full_loop_prefill(rng):

@@ -285,12 +285,29 @@ def test_paged_decode_kernel_interpret_bitwise_vs_dense(rng):
     vtok = kv_quant.kv_encode(
         jnp.asarray(rng.normal(size=(b, kvh, 1, hd)), jnp.float32))
     kl = jnp.asarray([t, t - 5], jnp.int32)
+    nb, _, bs, _ = paged["k"].shape
+    qr = jnp.asarray(rng.normal(size=(b, kvh, 2, hd)), jnp.float32)
     for tt in (4, 8):
         want = ad.decode_attn_q8(q, dense, ktok, vtok, kl,
                                  backend="pallas", interpret=True, tt=tt)
         got = ad.decode_attn_q8(q, paged, ktok, vtok, kl,
                                 backend="pallas", interpret=True, tt=tt)
         np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+        # the decode grid over the pool's own planes and the slot's block
+        # table == the row grid over pool rows and the per-row table
+        kw = dict(sm_scale=hd ** -0.5, tt=tt, interpret=True, block_size=bs)
+        fold = ad.attn_decode_q8_pallas(
+            qr, paged["k"], paged["k_scale"][..., 0], paged["v"],
+            paged["v_scale"][..., 0], kl, paged["table"], **kw)
+        rows = ad.attn_decode_q8_pallas(
+            qr.reshape(b * kvh, 2, hd), paged["k"].reshape(nb * kvh, bs, hd),
+            paged["k_scale"].reshape(nb * kvh, bs),
+            paged["v"].reshape(nb * kvh, bs, hd),
+            paged["v_scale"].reshape(nb * kvh, bs), jnp.repeat(kl, kvh),
+            ad.paged_row_table(paged["table"], kvh), **kw)
+        for a, w in zip(fold, rows):
+            np.testing.assert_array_equal(np.asarray(a).reshape(w.shape),
+                                          np.asarray(w))
 
 
 # ---------------------------------------------------------------------------

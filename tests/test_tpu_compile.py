@@ -160,6 +160,61 @@ def test_attention_compiles(one_chip, head_dim, paged, q_len):
                             *extra))
 
 
+# the benchmark cells' served decode shapes: (slots, pool blocks, table
+# width in 256-token blocks, kv heads, query heads per kv head)
+SERVED_DECODE = {
+    "qwen05b.chat": (64, 309, 10, 16, 1),
+    "smollm135m.chat": (128, 658, 8, 3, 3),
+    "qwen05b.longdecode": (12, 347, 40, 16, 1),
+}
+
+
+@pytest.mark.parametrize("cell", sorted(SERVED_DECODE))
+@pytest.mark.parametrize("paged", [False, True], ids=["dense", "paged"])
+def test_decode_grid_compiles_at_served_shapes(one_chip, cell, paged):
+    """The decode grid (a step per slot and key tile, every kv head in it)
+    at each cell's served shape, over the pool's own planes or a dense
+    cache of the same length: block rule and VMEM checked by the chip's
+    compiler, and the kernel keeps the instruction name the benchmark's
+    trace reduction matches."""
+    import re
+
+    slots, nb, maxb, kv, g = SERVED_DECODE[cell]
+    bs, hd = 256, 64
+    lead, t = (nb, bs) if paged else (slots, maxb * bs)
+    codes = _shape(one_chip, (lead, kv, t, hd), jnp.int8)
+    scale = _shape(one_chip, (lead, kv, t), jnp.float16)
+    extra = [_shape(one_chip, (slots, maxb), jnp.int32)] if paged else []
+    q = _shape(one_chip, (slots, kv, g, hd), jnp.float32)
+    lens = _shape(one_chip, (slots,), jnp.int32)
+    fn = lambda q_, kc, ks, vc, vs, kl, *tbl: attn_q8_pallas(
+        q_, kc, ks, vc, vs, kl, None, *tbl, sm_scale=hd ** -0.5,
+        causal=False, tt=256, interpret=False,
+        block_size=bs if paged else None)
+    compiled = _compile(fn, q, codes, scale, codes, scale, lens, *extra)
+    _assert_kernel(compiled)
+    assert re.search(r"^\s+(ROOT )?%attn_q8_pallas(\.\d+)? = ",
+                     compiled.as_text(), re.M)
+
+
+def test_decode_grid_vmem_budget_compiles(one_chip):
+    """A key tile asked wider than FOLD_VMEM_BUDGET allows for every kv
+    head's code blocks is narrowed from the shape, and the narrowed tile at
+    the budget's edge still fits v5e's scoped VMEM."""
+    from repro.kernels.attn_decode import FOLD_VMEM_BUDGET, _fold_tt
+
+    kv, hd, t = 16, 128, 4096
+    tt = _fold_tt(4096, kv, hd)
+    assert 4 * kv * tt * hd == FOLD_VMEM_BUDGET
+    codes = _shape(one_chip, (2, kv, t, hd), jnp.int8)
+    scale = _shape(one_chip, (2, kv, t), jnp.float16)
+    q = _shape(one_chip, (2, kv, 1, hd), jnp.float32)
+    lens = _shape(one_chip, (2,), jnp.int32)
+    fn = lambda *a: attn_q8_pallas(*a, sm_scale=hd ** -0.5, causal=False,
+                                   tt=4096, interpret=False)
+    _assert_kernel(_compile(fn, q, codes, scale, codes, scale, lens))
+
+
 @pytest.mark.parametrize(
     "tq,tt", attn_candidates(2048, 64) + attn_candidates(2048, 64,
                                                          decode=True))
